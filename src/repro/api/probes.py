@@ -44,6 +44,7 @@ import os
 import weakref
 from typing import Any, Callable, Mapping
 
+from repro import tracing
 from repro.core import measure, membench
 from repro.core.chains import OpSpec
 from repro.core.latency_db import LatencyRecord
@@ -445,8 +446,10 @@ class KernelChainProbe(Probe):
         per_timer = KernelChainProbe._baselines.setdefault(ctx.timer, {})
         if self.lens not in per_timer:
             base = next(o for o in chains.default_registry() if o.name == "add")
-            m = inkernel.measure_inkernel_full(base, lens=self.lens,
-                                               timer=ctx.timer, reps=self.reps)
+            with tracing.span("repro.session.setup"):
+                m = inkernel.measure_inkernel_full(base, lens=self.lens,
+                                                   timer=ctx.timer,
+                                                   reps=self.reps)
             per_timer[self.lens] = max(m.median_ns, 0.0) / (1 + base.guard)
         return per_timer[self.lens]
 

@@ -58,6 +58,7 @@ from typing import Any
 
 import jax
 
+from repro import tracing
 from repro.core import chains, measure
 from repro.core.compile_cache import CompileCache
 from repro.core.latency_db import (LatencyDB, LatencyRecord, ProbeFailure,
@@ -236,7 +237,8 @@ class Session:
                 if rec is not None:
                     ns = rec.latency_ns
                 else:
-                    with self._device_ctx():
+                    with tracing.span("repro.session.setup"), \
+                            self._device_ctx():
                         ns = measure.measure_op(base, opt_level, self.timer)
                 self._baseline[cache_key] = ns / (1 + base.guard)
         return self._baseline[cache_key]
@@ -266,29 +268,34 @@ class Session:
         force = self.force if force is None else force
         pipeline = self.pipeline if pipeline is None else pipeline
         plan = plan.dedupe()
-        ctx = self._context(force=force)
         probes = list(plan)
-        results: dict[int, ProbeResult] = {}
-        pending: list[tuple[int, Probe]] = []
-        for i, probe in enumerate(probes):
-            key = probe.key(self.env)
-            if not force and key in self.db:
-                results[i] = ProbeResult(probe, "cached", record=self.db.get(key))
-                logger.debug("cached   %-28s", probe.op + "@" + probe.opt_level)
-            else:
-                pending.append((i, probe))
-        stage_ns = {"compile": 0, "time": 0, "flush": 0}
-        stats0 = (dataclasses.replace(self.compile_cache.stats)
-                  if self.compile_cache is not None else None)
-        if pending:
-            if pipeline and len(pending) > 1:
-                self._run_pipelined(pending, ctx, results, stage_ns)
-            else:
-                self._run_serial(pending, ctx, results, stage_ns)
-        if self.db.path:
-            t0 = time.perf_counter_ns()
-            self.db.save()  # compact the journal into one atomic write
-            stage_ns["flush"] += time.perf_counter_ns() - t0
+        with tracing.span("repro.session.run", probes=len(probes)):
+            with tracing.span("repro.session.setup"):
+                ctx = self._context(force=force)
+            results: dict[int, ProbeResult] = {}
+            pending: list[tuple[int, Probe]] = []
+            for i, probe in enumerate(probes):
+                key = probe.key(self.env)
+                if not force and key in self.db:
+                    results[i] = ProbeResult(probe, "cached",
+                                             record=self.db.get(key))
+                    logger.debug("cached   %-28s",
+                                 probe.op + "@" + probe.opt_level)
+                else:
+                    pending.append((i, probe))
+            stage_ns = {"compile": 0, "time": 0, "flush": 0}
+            stats0 = (dataclasses.replace(self.compile_cache.stats)
+                      if self.compile_cache is not None else None)
+            if pending:
+                if pipeline and len(pending) > 1:
+                    self._run_pipelined(pending, ctx, results, stage_ns)
+                else:
+                    self._run_serial(pending, ctx, results, stage_ns)
+            if self.db.path:
+                t0 = time.perf_counter_ns()
+                with tracing.span("repro.session.flush", start_ns=t0):
+                    self.db.save()  # compact the journal into one atomic write
+                stage_ns["flush"] += time.perf_counter_ns() - t0
         cache_stats = None
         if stats0 is not None:
             now = self.compile_cache.stats
@@ -328,7 +335,8 @@ class Session:
             t0 = time.perf_counter_ns()
             prepared, exc, verdict = None, None, None
             try:
-                with self._device_ctx():
+                with tracing.span("repro.session.prepare", start_ns=t0,
+                                  op=probe.op), self._device_ctx():
                     prepared = _prepare_probe(probe, ctx)
                     verdict = self._audit_for(probe)
             except Exception as e:  # noqa: BLE001 - structured failure below
@@ -348,7 +356,8 @@ class Session:
         def _prepare(probe: Probe):
             t0 = time.perf_counter_ns()
             try:
-                with self._device_ctx():
+                with tracing.span("repro.session.prepare", start_ns=t0,
+                                  op=probe.op), self._device_ctx():
                     prepared = _prepare_probe(probe, ctx)
                     verdict = self._audit_for(probe)
                 return prepared, None, verdict, time.perf_counter_ns() - t0
@@ -366,7 +375,8 @@ class Session:
                     # the worker moves straight on to probe N+1 while the
                     # main thread times probe N below
                     fut = pool.submit(_prepare, pending[j + 1][1])
-                prepared, exc, verdict, compile_ns = cur.result()
+                with tracing.span("repro.session.compile_wait"):
+                    prepared, exc, verdict, compile_ns = cur.result()
                 stage_ns["compile"] += compile_ns
                 self._finish_probe(i, probe, ctx, prepared, exc, results,
                                    stage_ns, verdict=verdict)
@@ -379,7 +389,8 @@ class Session:
         if exc is None:
             t0 = time.perf_counter_ns()
             try:
-                with self._device_ctx():
+                with tracing.span("repro.session.time", start_ns=t0,
+                                  op=probe.op), self._device_ctx():
                     rec = _execute_probe(probe, ctx, prepared)
             except Exception as e:  # noqa: BLE001 - recorded as failure
                 exc = e
@@ -407,7 +418,8 @@ class Session:
             logger.warning("probe %s@%s failed: %s: %s", probe.op,
                            probe.opt_level, type(exc).__name__, exc)
         t0 = time.perf_counter_ns()
-        self._flush()
+        with tracing.span("repro.session.flush", start_ns=t0):
+            self._flush()
         stage_ns["flush"] += time.perf_counter_ns() - t0
 
     def _flush(self) -> None:

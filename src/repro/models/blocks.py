@@ -78,19 +78,20 @@ def attn_train(p: Params, x, cfg: ModelConfig, rt: Runtime, positions,
 
     ``kv``: optional encoder memory for cross-attention (bidirectional).
     """
-    h = common.rmsnorm(x, p["norm"].value) if cfg.norm == "rmsnorm" else x
-    src = h if kv is None else kv
-    q = jnp.einsum("bsd,dhk->bshk", h, _w(p, "wq", cfg.cdtype, rt))
-    k = jnp.einsum("bsd,dhk->bshk", src, _w(p, "wk", cfg.cdtype, rt))
-    v = jnp.einsum("bsd,dhk->bshk", src, _w(p, "wv", cfg.cdtype, rt))
-    if kv is None:
-        q, k = _rope(cfg, q, k, positions)
-    q, k, v = _annotate_qkv(cfg, q, k, v)
-    out = common.attention(q, k, v, causal=causal and kv is None,
-                           impl=rt.attn_impl, block_k=rt.block_k,
-                           p_dtype=jnp.dtype(rt.attn_p_dtype))
-    y = jnp.einsum("bshk,hkd->bsd", out, _w(p, "wo", cfg.cdtype, rt))
-    return x + annotate(y, "batch", "seq", None), (k, v)
+    with jax.named_scope("attn"):
+        h = common.rmsnorm(x, p["norm"].value) if cfg.norm == "rmsnorm" else x
+        src = h if kv is None else kv
+        q = jnp.einsum("bsd,dhk->bshk", h, _w(p, "wq", cfg.cdtype, rt))
+        k = jnp.einsum("bsd,dhk->bshk", src, _w(p, "wk", cfg.cdtype, rt))
+        v = jnp.einsum("bsd,dhk->bshk", src, _w(p, "wv", cfg.cdtype, rt))
+        if kv is None:
+            q, k = _rope(cfg, q, k, positions)
+        q, k, v = _annotate_qkv(cfg, q, k, v)
+        out = common.attention(q, k, v, causal=causal and kv is None,
+                               impl=rt.attn_impl, block_k=rt.block_k,
+                               p_dtype=jnp.dtype(rt.attn_p_dtype))
+        y = jnp.einsum("bshk,hkd->bsd", out, _w(p, "wo", cfg.cdtype, rt))
+        return x + annotate(y, "batch", "seq", None), (k, v)
 
 
 def init_attn_cache(cfg: ModelConfig, batch: int, max_len: int, dtype) -> Params:
@@ -111,36 +112,42 @@ def attn_decode(p: Params, x, cache: Params, pos, cfg: ModelConfig, rt: Runtime,
     depth, so the KV write is a per-row scatter and the attention mask a
     per-row ``kv_len``).
     """
-    b = x.shape[0]
-    h = common.rmsnorm(x, p["norm"].value) if cfg.norm == "rmsnorm" else x
-    q, k, v = _project_qkv(p, h, cfg)
-    pos_arr = jnp.asarray(pos, jnp.int32)
-    if positions is None:
-        positions = (jnp.full((b, 1), pos_arr, jnp.int32)
-                     if pos_arr.ndim == 0 else pos_arr[:, None])
-    q, k = _rope(cfg, q, k, positions)
-    if pos_arr.ndim == 0:
-        ck = lax.dynamic_update_slice_in_dim(cache["k"], k.astype(cache["k"].dtype), pos, axis=1)
-        cv = lax.dynamic_update_slice_in_dim(cache["v"], v.astype(cache["v"].dtype), pos, axis=1)
-    else:
-        rows = jnp.arange(b)
-        ck = cache["k"].at[rows, pos_arr].set(k[:, 0].astype(cache["k"].dtype))
-        cv = cache["v"].at[rows, pos_arr].set(v[:, 0].astype(cache["v"].dtype))
-    if rt.cache_shard == "head_dim":
-        # split-K layout: the in-place cache write stays shard-local (a DUS
-        # into a seq-sharded buffer makes GSPMD all-gather the whole cache —
-        # measured 16 GiB/step on jamba long_500k; §Perf).
-        ck = annotate(ck, "batch", None, None, "kv_hd")
-        cv = annotate(cv, "batch", None, None, "kv_hd")
-    elif cfg.attn_parallelism == "heads":
-        ck = annotate(ck, "batch", "kv_seq", "kv_heads", None)
-        cv = annotate(cv, "batch", "kv_seq", "kv_heads", None)
-    else:
-        ck = annotate(ck, "batch", "kv_seq", None, None)
-        cv = annotate(cv, "batch", "kv_seq", None, None)
-    out = common.decode_attention(q[:, 0], ck, cv, kv_len=pos + 1)
-    y = jnp.einsum("bhk,hkd->bd", out, p["wo"].value.astype(cfg.cdtype))[:, None]
-    return x + y, {"k": ck, "v": cv}
+    with jax.named_scope("attn"):
+        b = x.shape[0]
+        h = common.rmsnorm(x, p["norm"].value) if cfg.norm == "rmsnorm" else x
+        q, k, v = _project_qkv(p, h, cfg)
+        pos_arr = jnp.asarray(pos, jnp.int32)
+        if positions is None:
+            positions = (jnp.full((b, 1), pos_arr, jnp.int32)
+                         if pos_arr.ndim == 0 else pos_arr[:, None])
+        q, k = _rope(cfg, q, k, positions)
+        if pos_arr.ndim == 0:
+            ck = lax.dynamic_update_slice_in_dim(
+                cache["k"], k.astype(cache["k"].dtype), pos, axis=1)
+            cv = lax.dynamic_update_slice_in_dim(
+                cache["v"], v.astype(cache["v"].dtype), pos, axis=1)
+        else:
+            rows = jnp.arange(b)
+            ck = cache["k"].at[rows, pos_arr].set(
+                k[:, 0].astype(cache["k"].dtype))
+            cv = cache["v"].at[rows, pos_arr].set(
+                v[:, 0].astype(cache["v"].dtype))
+        if rt.cache_shard == "head_dim":
+            # split-K layout: the in-place cache write stays shard-local (a
+            # DUS into a seq-sharded buffer makes GSPMD all-gather the whole
+            # cache — measured 16 GiB/step on jamba long_500k; §Perf).
+            ck = annotate(ck, "batch", None, None, "kv_hd")
+            cv = annotate(cv, "batch", None, None, "kv_hd")
+        elif cfg.attn_parallelism == "heads":
+            ck = annotate(ck, "batch", "kv_seq", "kv_heads", None)
+            cv = annotate(cv, "batch", "kv_seq", "kv_heads", None)
+        else:
+            ck = annotate(ck, "batch", "kv_seq", None, None)
+            cv = annotate(cv, "batch", "kv_seq", None, None)
+        out = common.decode_attention(q[:, 0], ck, cv, kv_len=pos + 1)
+        y = jnp.einsum("bhk,hkd->bd", out,
+                       p["wo"].value.astype(cfg.cdtype))[:, None]
+        return x + y, {"k": ck, "v": cv}
 
 
 def attn_cross_decode(p: Params, x, mem_kv, cfg: ModelConfig):
@@ -166,13 +173,14 @@ def init_mlp(key, cfg: ModelConfig, d_ff: int | None = None) -> Params:
 
 
 def mlp_apply(p: Params, x, cfg: ModelConfig, rt: Runtime | None = None):
-    h = common.rmsnorm(x, p["norm"].value)
-    cd = cfg.cdtype
-    g = jnp.einsum("bsd,df->bsf", h, _w(p, "wg", cd, rt))
-    u = jnp.einsum("bsd,df->bsf", h, _w(p, "wu", cd, rt))
-    g = annotate(jax.nn.silu(g) * u, "batch", "seq", "act_mlp")
-    y = jnp.einsum("bsf,fd->bsd", g, _w(p, "wd", cd, rt))
-    return x + annotate(y, "batch", "seq", None)
+    with jax.named_scope("mlp"):
+        h = common.rmsnorm(x, p["norm"].value)
+        cd = cfg.cdtype
+        g = jnp.einsum("bsd,df->bsf", h, _w(p, "wg", cd, rt))
+        u = jnp.einsum("bsd,df->bsf", h, _w(p, "wu", cd, rt))
+        g = annotate(jax.nn.silu(g) * u, "batch", "seq", "act_mlp")
+        y = jnp.einsum("bsf,fd->bsd", g, _w(p, "wd", cd, rt))
+        return x + annotate(y, "batch", "seq", None)
 
 
 # ================================================================= MoE block

@@ -4,6 +4,11 @@ Layers are scanned per *period* (config.period); parameters and KV caches are
 stacked over periods so the HLO stays compact at 126 layers, with costs
 corrected for trip counts by the static analyzer. All functions take BOXED
 params (Param leaves); jit shardings are derived from the boxes.
+
+Named scopes (``jax.named_scope``) mark the model's parts in every op's
+``op_name``, and so in a profiler trace: ``embed``, ``layers`` (the scan
+over periods), ``attn`` and ``mlp`` (``models/blocks.py``) inside it, and
+``head`` (final norm and logits).
 """
 from __future__ import annotations
 
@@ -121,11 +126,12 @@ def init_lm(key, cfg: ModelConfig) -> Params:
 
 
 def _embed_in(params: Params, cfg: ModelConfig, tokens=None, embeds=None):
-    if embeds is not None:
-        x = embeds.astype(cfg.cdtype)
-    else:
-        x = params["embed"].value.astype(cfg.cdtype)[tokens]
-    return annotate(x, "batch", "seq", None)
+    with jax.named_scope("embed"):
+        if embeds is not None:
+            x = embeds.astype(cfg.cdtype)
+        else:
+            x = params["embed"].value.astype(cfg.cdtype)[tokens]
+        return annotate(x, "batch", "seq", None)
 
 
 def _out_embed(params: Params, cfg: ModelConfig):
@@ -158,19 +164,23 @@ def forward(params: Params, cfg: ModelConfig, rt: Runtime, *, tokens=None,
         return (x, aux + a), caches
 
     body_fn = jax.checkpoint(body) if rt.remat else body
-    if rt.scan_layers:
-        (x, aux), caches = lax.scan(body_fn, (x, jnp.zeros((), jnp.float32)),
-                                    params["periods"])
-    else:
-        aux = jnp.zeros((), jnp.float32)
-        caches_list = []
-        for i in range(cfg.n_periods):
-            pp = jax.tree_util.tree_map(lambda a, i=i: a[i], params["periods"])
-            (x, aux), c = body_fn((x, aux), pp)
-            caches_list.append(c)
-        caches = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *caches_list) \
-            if want_cache and caches_list else {}
-    h = common.rmsnorm(x, params["final_norm"].value)
+    with jax.named_scope("layers"):
+        if rt.scan_layers:
+            (x, aux), caches = lax.scan(
+                body_fn, (x, jnp.zeros((), jnp.float32)), params["periods"])
+        else:
+            aux = jnp.zeros((), jnp.float32)
+            caches_list = []
+            for i in range(cfg.n_periods):
+                pp = jax.tree_util.tree_map(lambda a, i=i: a[i],
+                                            params["periods"])
+                (x, aux), c = body_fn((x, aux), pp)
+                caches_list.append(c)
+            caches = jax.tree_util.tree_map(
+                lambda *xs: jnp.stack(xs), *caches_list) \
+                if want_cache and caches_list else {}
+    with jax.named_scope("head"):
+        h = common.rmsnorm(x, params["final_norm"].value)
     return h, aux, caches
 
 
@@ -217,12 +227,14 @@ def prefill(params: Params, cfg: ModelConfig, rt: Runtime, *, tokens=None,
     """
     h, _, caches = forward(params, cfg, rt, tokens=tokens, embeds=embeds,
                            positions=positions, want_cache=True)
-    if last_positions is None:
-        last = h[:, -1]
-    else:
-        last = jnp.take_along_axis(
-            h, last_positions.astype(jnp.int32)[:, None, None], axis=1)[:, 0]
-    logits = common.top1_logits(last, _out_embed(params, cfg))
+    with jax.named_scope("head"):
+        if last_positions is None:
+            last = h[:, -1]
+        else:
+            last = jnp.take_along_axis(
+                h, last_positions.astype(jnp.int32)[:, None, None],
+                axis=1)[:, 0]
+        logits = common.top1_logits(last, _out_embed(params, cfg))
     return logits, caches
 
 
@@ -240,7 +252,9 @@ def decode_step(params: Params, cache: Params, tokens, pos, cfg: ModelConfig,
             new_c[f"l{i}"] = c
         return x, new_c
 
-    x, new_cache = lax.scan(body, x, (params["periods"], cache))
-    h = common.rmsnorm(x, params["final_norm"].value)
-    logits = common.top1_logits(h[:, 0], _out_embed(params, cfg))
+    with jax.named_scope("layers"):
+        x, new_cache = lax.scan(body, x, (params["periods"], cache))
+    with jax.named_scope("head"):
+        h = common.rmsnorm(x, params["final_norm"].value)
+        logits = common.top1_logits(h[:, 0], _out_embed(params, cfg))
     return logits, new_cache

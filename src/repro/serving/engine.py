@@ -38,6 +38,7 @@ import numpy as np
 
 from jax import lax
 
+from repro import tracing
 from repro.models import transformer
 from repro.models.config import ModelConfig, Runtime
 
@@ -57,12 +58,18 @@ class Engine:
         self.cfg = cfg
         self.rt = rt
         self.max_len = max_len
-        self._prefill = jax.jit(
-            lambda p, t, last: transformer.prefill(p, cfg, rt, tokens=t,
-                                                   last_positions=last))
-        self._decode = jax.jit(
-            lambda p, c, t, pos: transformer.decode_step(p, c, t, pos, cfg, rt),
-            donate_argnums=(1,))
+
+        # named functions, so that the profiler's modules read jit_prefill
+        # and jit_decode_step
+        def prefill(p, t, last):
+            return transformer.prefill(p, cfg, rt, tokens=t,
+                                       last_positions=last)
+
+        def decode_step(p, c, t, pos):
+            return transformer.decode_step(p, c, t, pos, cfg, rt)
+
+        self._prefill = jax.jit(prefill)
+        self._decode = jax.jit(decode_step, donate_argnums=(1,))
 
     def generate(self, prompts: list[list[int]], *, max_new: int = 32,
                  temperature: float = 0.0, seed: int = 0,
@@ -142,8 +149,11 @@ class Engine:
                                        self.cfg.cdtype)
         toks = jnp.zeros((batch, 1), jnp.int32)
         cfg, rt = self.cfg, self.rt
-        fn = jax.jit(lambda p, c, t: transformer.decode_step(
-            p, c, t, prompt_len, cfg, rt))
+
+        def decode_step(p, c, t):
+            return transformer.decode_step(p, c, t, prompt_len, cfg, rt)
+
+        fn = jax.jit(decode_step)
         args = (self.params, cache, toks)
         return fn.lower(*args), args
 
@@ -158,6 +168,15 @@ def _sample(logits: jax.Array, temperature: float, key) -> jax.Array:
     if temperature <= 0.0:
         return jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
     return jax.random.categorical(key, logits / temperature)[:, None].astype(jnp.int32)
+
+
+def write_slot(cache, pc, slot):
+    """``pc``, a batch-1 prefill cache, written into row ``slot`` of the
+    pool's ``cache``."""
+    return jax.tree_util.tree_map(
+        lambda big, small: lax.dynamic_update_slice(
+            big, small.astype(big.dtype), (0, slot) + (0,) * (big.ndim - 2)),
+        cache, pc)
 
 
 @dataclasses.dataclass
@@ -207,13 +226,7 @@ class SlotPool:
         self._tok = np.zeros((self.n_slots, 1), np.int32)  # last sampled token
         # admit writes the batch-1 prefill cache into one slot's rows; the
         # pool cache is donated (replaced wholesale every admit/step)
-        self._write = jax.jit(
-            lambda cache, pc, slot: jax.tree_util.tree_map(
-                lambda big, small: lax.dynamic_update_slice(
-                    big, small.astype(big.dtype),
-                    (0, slot) + (0,) * (big.ndim - 2)),
-                cache, pc),
-            donate_argnums=(0,))
+        self._write = jax.jit(write_slot, donate_argnums=(0,))
 
     # ------------------------------------------------------------- queries
     def free_slots(self) -> list[int]:
@@ -245,12 +258,16 @@ class SlotPool:
                 f"prompt ({len(prompt)}) + max_new ({max_new}) exceeds the "
                 f"pool's max_len ({self.max_len})")
         eng = self.engine
-        toks = jnp.asarray(np.asarray(prompt, np.int32)[None])
-        last = jnp.asarray([len(prompt) - 1], jnp.int32)
-        logits, pc = eng._prefill(eng.params, toks, last)
-        self.cache = self._write(self.cache, pc, slot)
-        st.uid, st.pos, st.n_generated, st.active = uid, len(prompt), 0, True
-        tok = int(np.asarray(self._sample_slot(logits, st))[0, 0])
+        with tracing.span("repro.pool.admit", uid=uid, prompt_len=len(prompt)):
+            with tracing.span("repro.pool.prefill"):
+                toks = jnp.asarray(np.asarray(prompt, np.int32)[None])
+                last = jnp.asarray([len(prompt) - 1], jnp.int32)
+                logits, pc = eng._prefill(eng.params, toks, last)
+            with tracing.span("repro.pool.write"):
+                self.cache = self._write(self.cache, pc, slot)
+            st.uid, st.pos, st.n_generated, st.active = uid, len(prompt), 0, True
+            with tracing.span("repro.pool.first_token"):
+                tok = int(np.asarray(self._sample_slot(logits, st))[0, 0])
         self._tok[slot, 0] = tok
         # pos stays at len(prompt): the first generated token's KV is written
         # by the *next* decode step, at exactly that position
@@ -268,27 +285,36 @@ class SlotPool:
         decoding garbage into their own (unread) rows, exactly the static
         batch's waste-slot behavior, because the compiled step's shape is
         fixed at ``n_slots``."""
-        if not any(s.active for s in self._slots):
+        active = sum(s.active for s in self._slots)
+        if not active:
             raise ValueError("step() with no active slot")
         eng = self.engine
-        pos = jnp.asarray([s.pos for s in self._slots], jnp.int32)
-        logits, self.cache = eng._decode(eng.params, self.cache,
-                                         jnp.asarray(self._tok), pos)
-        out = np.asarray(jnp.argmax(logits, axis=-1).astype(jnp.int32)).copy()
-        if self.temperature > 0.0:
-            # sample only the occupied rows: free slots keep their greedy
-            # garbage (never surfaced), and their sentinel uid must not
-            # consume — or crash — a PRNG stream
+        # the slot bookkeeping after the read-back is the step's self time
+        with tracing.span("repro.pool.step", active=active):
+            with tracing.span("repro.pool.step_inputs"):
+                pos = jnp.asarray([s.pos for s in self._slots], jnp.int32)
+                tok = jnp.asarray(self._tok)
+            with tracing.span("repro.pool.decode"):
+                logits, self.cache = eng._decode(eng.params, self.cache, tok,
+                                                 pos)
+            with tracing.span("repro.pool.tokens"):  # waits for the device
+                out = np.asarray(
+                    jnp.argmax(logits, axis=-1).astype(jnp.int32)).copy()
+            if self.temperature > 0.0:
+                # sample only the occupied rows: free slots keep their greedy
+                # garbage (never surfaced), and their sentinel uid must not
+                # consume — or crash — a PRNG stream
+                with tracing.span("repro.pool.sample"):
+                    for i, st in enumerate(self._slots):
+                        if st.active:
+                            row = _sample(logits[i:i + 1], self.temperature,
+                                          self._slot_key(st))
+                            out[i] = int(np.asarray(row)[0, 0])
             for i, st in enumerate(self._slots):
+                self._tok[i, 0] = out[i]
                 if st.active:
-                    row = _sample(logits[i:i + 1], self.temperature,
-                                  self._slot_key(st))
-                    out[i] = int(np.asarray(row)[0, 0])
-        for i, st in enumerate(self._slots):
-            self._tok[i, 0] = out[i]
-            if st.active:
-                st.pos += 1
-                st.n_generated += 1
+                    st.pos += 1
+                    st.n_generated += 1
         return out
 
     # ------------------------------------------------------------- sampling

@@ -19,6 +19,11 @@ arrival when the pool drains — so a trace replays deterministically (no
 sleeping, no load generator) while the measured run still prices every step
 on the actual engine. TTFT is first-token-completion minus arrival, which
 includes queueing delay: that is the number production SLOs bound.
+
+With ``repro.tracing`` on, every admission and decode step is also a span
+on the wall clock (``repro.sched.admit`` with the request's ``uid`` and the
+virtual-clock ns it ``queued``; ``repro.sched.step`` with the ``active``
+slot count), whatever the virtual clock says.
 """
 from __future__ import annotations
 
@@ -29,6 +34,7 @@ from typing import Protocol, Sequence
 
 import numpy as np
 
+from repro import tracing
 from repro.traffic.traces import Request
 from repro.utils import block, logger
 
@@ -130,7 +136,9 @@ class ContinuousBatchingScheduler:
                 req = pending.popleft()
                 slot = free.pop(0)
                 rr = RequestResult(request=req, slot=slot, admitted_ns=clock)
-                tok, cost = ex.admit(slot, req)
+                with tracing.span("repro.sched.admit", uid=req.uid,
+                                  queued=clock - req.arrival_ns):
+                    tok, cost = ex.admit(slot, req)
                 clock += cost
                 rr.first_token_ns = clock
                 rr.tokens.append(tok)
@@ -146,7 +154,8 @@ class ContinuousBatchingScheduler:
                 continue        # new arrivals may have become eligible
             # -------------------------------------------------- 2. decode
             if active:
-                toks, cost = ex.step()
+                with tracing.span("repro.sched.step", active=len(active)):
+                    toks, cost = ex.step()
                 clock += cost
                 decode_steps += 1
                 for slot in sorted(active):
