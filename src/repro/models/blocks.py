@@ -102,16 +102,23 @@ def init_attn_cache(cfg: ModelConfig, batch: int, max_len: int, dtype) -> Params
     }
 
 
-def attn_decode(p: Params, x, cache: Params, pos, cfg: ModelConfig, rt: Runtime,
-                positions=None):
-    """One-token step. x: [B,1,D]; cache k/v: [B,Smax,KH,hd].
+def _annotate_cache(c, cfg: ModelConfig, rt: Runtime, lead: tuple = ()):
+    """Constrain a decode K or V cache; ``lead`` names leading axes (the
+    stacked cache's period axis, unsharded)."""
+    if rt.cache_shard == "head_dim":
+        # split-K layout: the in-place cache write stays shard-local (a
+        # DUS into a seq-sharded buffer makes GSPMD all-gather the whole
+        # cache — measured 16 GiB/step on jamba long_500k; §Perf).
+        return annotate(c, *lead, "batch", None, None, "kv_hd")
+    if cfg.attn_parallelism == "heads":
+        return annotate(c, *lead, "batch", "kv_seq", "kv_heads", None)
+    return annotate(c, *lead, "batch", "kv_seq", None, None)
 
-    ``pos`` is either a scalar (the whole batch decodes in lockstep at one
-    position — the static-batch path) or a ``[B]`` int32 array of *per-row*
-    positions (the continuous-batching path: every slot sits at its own
-    depth, so the KV write is a per-row scatter and the attention mask a
-    per-row ``kv_len``).
-    """
+
+def _attn_decode(p: Params, x, pos, cfg: ModelConfig, positions, write):
+    """The one-token attention step both cache forms share: norm, q/k/v,
+    RoPE, then ``write(k, v, pos_arr) -> (ck, cv, cache)`` stores the
+    token's K/V and returns the [B,Smax,KH,hd] views to attend over."""
     with jax.named_scope("attn"):
         b = x.shape[0]
         h = common.rmsnorm(x, p["norm"].value) if cfg.norm == "rmsnorm" else x
@@ -121,33 +128,66 @@ def attn_decode(p: Params, x, cache: Params, pos, cfg: ModelConfig, rt: Runtime,
             positions = (jnp.full((b, 1), pos_arr, jnp.int32)
                          if pos_arr.ndim == 0 else pos_arr[:, None])
         q, k = _rope(cfg, q, k, positions)
+        ck, cv, cache = write(k, v, pos_arr)
+        out = common.decode_attention(q[:, 0], ck, cv, kv_len=pos + 1)
+        y = jnp.einsum("bhk,hkd->bd", out,
+                       p["wo"].value.astype(cfg.cdtype))[:, None]
+        return x + y, cache
+
+
+def attn_decode(p: Params, x, cache: Params, pos, cfg: ModelConfig, rt: Runtime,
+                positions=None):
+    """One-token step against one layer's cache. x: [B,1,D]; cache k/v:
+    [B,Smax,KH,hd].
+
+    ``pos`` is either a scalar (the whole batch decodes in lockstep at one
+    position — the static-batch path) or a ``[B]`` int32 array of *per-row*
+    positions (the continuous-batching path: every slot sits at its own
+    depth, so the KV write is a per-row scatter and the attention mask a
+    per-row ``kv_len``). The decoder-only scan calls
+    :func:`attn_decode_stacked` instead.
+    """
+    def write(k, v, pos_arr):
         if pos_arr.ndim == 0:
             ck = lax.dynamic_update_slice_in_dim(
                 cache["k"], k.astype(cache["k"].dtype), pos, axis=1)
             cv = lax.dynamic_update_slice_in_dim(
                 cache["v"], v.astype(cache["v"].dtype), pos, axis=1)
         else:
-            rows = jnp.arange(b)
+            rows = jnp.arange(k.shape[0])
             ck = cache["k"].at[rows, pos_arr].set(
                 k[:, 0].astype(cache["k"].dtype))
             cv = cache["v"].at[rows, pos_arr].set(
                 v[:, 0].astype(cache["v"].dtype))
-        if rt.cache_shard == "head_dim":
-            # split-K layout: the in-place cache write stays shard-local (a
-            # DUS into a seq-sharded buffer makes GSPMD all-gather the whole
-            # cache — measured 16 GiB/step on jamba long_500k; §Perf).
-            ck = annotate(ck, "batch", None, None, "kv_hd")
-            cv = annotate(cv, "batch", None, None, "kv_hd")
-        elif cfg.attn_parallelism == "heads":
-            ck = annotate(ck, "batch", "kv_seq", "kv_heads", None)
-            cv = annotate(cv, "batch", "kv_seq", "kv_heads", None)
-        else:
-            ck = annotate(ck, "batch", "kv_seq", None, None)
-            cv = annotate(cv, "batch", "kv_seq", None, None)
-        out = common.decode_attention(q[:, 0], ck, cv, kv_len=pos + 1)
-        y = jnp.einsum("bhk,hkd->bd", out,
-                       p["wo"].value.astype(cfg.cdtype))[:, None]
-        return x + y, {"k": ck, "v": cv}
+        ck, cv = _annotate_cache(ck, cfg, rt), _annotate_cache(cv, cfg, rt)
+        return ck, cv, {"k": ck, "v": cv}
+
+    return _attn_decode(p, x, pos, cfg, positions, write)
+
+
+def attn_decode_stacked(p: Params, x, cache: Params, period, pos,
+                        cfg: ModelConfig, rt: Runtime, positions=None):
+    """:func:`attn_decode` against the whole stacked cache, k/v
+    [P,B,Smax,KH,hd], which rides in the layer scan's carry: the token's
+    K/V is scattered in place at ``(period, row, pos)`` and attention reads
+    ``cache[period]`` through a dynamic index, so nothing is written back
+    and the stack is never copied.
+    """
+    def write(k, v, pos_arr):
+        # a scalar pos is scattered to every row too: as a
+        # dynamic_update_slice, XLA copies the whole stack in and out
+        rows = jnp.arange(k.shape[0])
+        at = (period, rows, jnp.broadcast_to(pos_arr, rows.shape))
+
+        def put(c, new):
+            c = c.at[at].set(new[:, 0].astype(c.dtype))
+            return _annotate_cache(c, cfg, rt, lead=(None,))
+        ck, cv = put(cache["k"], k), put(cache["v"], v)
+        return (lax.dynamic_index_in_dim(ck, period, keepdims=False),
+                lax.dynamic_index_in_dim(cv, period, keepdims=False),
+                {"k": ck, "v": cv})
+
+    return _attn_decode(p, x, pos, cfg, positions, write)
 
 
 def attn_cross_decode(p: Params, x, mem_kv, cfg: ModelConfig):

@@ -2,8 +2,10 @@
 
 Layers are scanned per *period* (config.period); parameters and KV caches are
 stacked over periods so the HLO stays compact at 126 layers, with costs
-corrected for trip counts by the static analyzer. All functions take BOXED
-params (Param leaves); jit shardings are derived from the boxes.
+corrected for trip counts by the static analyzer. The decode step carries the
+whole stacked cache through its scan and writes each token in place at its
+period's index. All functions take BOXED params (Param leaves); jit shardings
+are derived from the boxes.
 
 Named scopes (``jax.named_scope``) mark the model's parts in every op's
 ``op_name``, and so in a profiler trace: ``embed``, ``layers`` (the scan
@@ -68,17 +70,28 @@ def block_train(p: Params, x, layer: Layer, cfg: ModelConfig, rt: Runtime,
     return x, aux, cache
 
 
-def block_decode(p: Params, x, cache: Params, pos, layer: Layer,
+_STATE_DECODE = {"mamba": ssm.mamba_decode, "mlstm": xlstm.mlstm_decode,
+                 "slstm": xlstm.slstm_decode}
+
+
+def block_decode(p: Params, x, cache: Params, period, pos, layer: Layer,
                  cfg: ModelConfig, rt: Runtime, positions=None):
+    """One layer's decode step against its STACKED cache (leaves [P,...]):
+    returns ``x`` and the stack with row ``period`` updated in place."""
     mixer, ffn = layer
     if mixer == "attn":
-        x, cache = blocks.attn_decode(p["mixer"], x, cache, pos, cfg, rt, positions)
-    elif mixer == "mamba":
-        x, cache = ssm.mamba_decode(p["mixer"], x, cache, cfg)
-    elif mixer == "mlstm":
-        x, cache = xlstm.mlstm_decode(p["mixer"], x, cache, cfg)
-    elif mixer == "slstm":
-        x, cache = xlstm.slstm_decode(p["mixer"], x, cache, cfg)
+        x, cache = blocks.attn_decode_stacked(p["mixer"], x, cache, period, pos,
+                                              cfg, rt, positions)
+    elif mixer in _STATE_DECODE:
+        # recurrent states are small: read the period's, write it back whole
+        x, new = _STATE_DECODE[mixer](
+            p["mixer"], x,
+            jax.tree_util.tree_map(
+                lambda a: lax.dynamic_index_in_dim(a, period, keepdims=False),
+                cache), cfg)
+        cache = jax.tree_util.tree_map(
+            lambda a, n: lax.dynamic_update_index_in_dim(
+                a, n.astype(a.dtype), period, 0), cache, new)
     if ffn == "dense":
         x = blocks.mlp_apply(p["ffn"], x, cfg, rt)
     elif ffn == "moe":
@@ -240,20 +253,30 @@ def prefill(params: Params, cfg: ModelConfig, rt: Runtime, *, tokens=None,
 
 def decode_step(params: Params, cache: Params, tokens, pos, cfg: ModelConfig,
                 rt: Runtime, positions=None):
-    """One token for the whole batch. tokens: [B,1]; pos: scalar int."""
+    """One token for the whole batch. tokens: [B,1]; pos: scalar int or [B]
+    per-row positions.
+
+    The stacked cache rides in the layer scan's carry, and each layer writes
+    its token into it in place (``block_decode``): scanning the cache as
+    ``xs``/``ys`` instead would slice every period's K/V out, write it back
+    into a second stack and copy that whole stack out, every step.
+    """
     x = _embed_in(params, cfg, tokens)
 
-    def body(x, xs):
-        pp, pc = xs
-        new_c = {}
+    def body(carry, xs):
+        x, cache = carry
+        pp, period = xs
+        cache = dict(cache)
         for i, layer in enumerate(cfg.period):
-            x, c = block_decode(pp[f"l{i}"], x, pc[f"l{i}"], pos, layer, cfg,
-                                rt, positions)
-            new_c[f"l{i}"] = c
-        return x, new_c
+            x, cache[f"l{i}"] = block_decode(pp[f"l{i}"], x, cache[f"l{i}"],
+                                             period, pos, layer, cfg, rt,
+                                             positions)
+        return (x, cache), None
 
     with jax.named_scope("layers"):
-        x, new_cache = lax.scan(body, x, (params["periods"], cache))
+        (x, new_cache), _ = lax.scan(
+            body, (x, cache),
+            (params["periods"], jnp.arange(cfg.n_periods, dtype=jnp.int32)))
     with jax.named_scope("head"):
         h = common.rmsnorm(x, params["final_norm"].value)
         logits = common.top1_logits(h[:, 0], _out_embed(params, cfg))

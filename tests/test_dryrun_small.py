@@ -86,3 +86,48 @@ def test_cache_shardings_divisibility():
     # batch=1 and seq=7 not divisible by anything >1 -> fully replicated
     spec = sh["l0"]["k"].spec
     assert all(s is None for s in spec)
+
+
+@pytest.mark.parametrize("mode", ["seq", "head_dim"])
+def test_small_mesh_decode_step_lowers(mode):
+    """The decode step, with the stacked cache carried through the layer scan
+    and sharded as ``launch/cells.py`` shards it, compiles on a (2 pod,
+    2 data, 2 model) mesh for a dense and a hybrid period, donating the
+    cache in place."""
+    out = run_with_devices(f"""
+import jax, jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.configs.registry import get
+from repro.launch.cells import cache_shardings
+from repro.launch.mesh import make_mesh
+from repro.models import transformer
+from repro.models.config import Runtime
+from repro.parallel import sharding as shd
+
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
+rules = shd.lm_rules()
+batch = P(("pod", "data"))
+for arch in ("granite-3-8b", "jamba-v0.1-52b"):
+    cfg = get(arch).smoke
+    rt = Runtime(moe_groups=4, cache_shard={mode!r})
+    with shd.use_sharding(mesh, rules):
+        params = jax.eval_shape(lambda k: transformer.init_lm(k, cfg),
+                                jax.random.PRNGKey(0))
+        cache = jax.eval_shape(
+            lambda: transformer.init_cache(cfg, 8, 256, cfg.cdtype))
+        c_sh = cache_shardings(cache, mesh, ("pod", "data"), mode={mode!r})
+        tok_sh = NamedSharding(mesh, P(("pod", "data"), None))
+        step = jax.jit(
+            lambda p, c, t, q: transformer.decode_step(p, c, t, q, cfg, rt),
+            in_shardings=(shd.param_shardings(params, mesh, rules), c_sh,
+                          tok_sh, NamedSharding(mesh, batch)),
+            out_shardings=(tok_sh, c_sh), donate_argnums=(1,))
+        compiled = step.lower(params, cache,
+                              jax.ShapeDtypeStruct((8, 1), jnp.int32),
+                              jax.ShapeDtypeStruct((8,), jnp.int32)).compile()
+        cache_bytes = sum(a.size * a.dtype.itemsize
+                          for a in jax.tree_util.tree_leaves(cache)) // 8
+        assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes
+print("COMPILED")
+""", n_devices=8, timeout=480)
+    assert "COMPILED" in out

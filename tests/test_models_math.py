@@ -178,3 +178,92 @@ def test_slstm_decode_matches_train():
     y_dec, _ = xlstm.slstm_decode(p, x[:, 8:9], cache, cfg)
     np.testing.assert_allclose(np.asarray(y_dec[:, 0]), np.asarray(y_full[:, 8]),
                                atol=1e-5, rtol=1e-5)
+
+
+# ------------------------------------------------- decode step, stacked cache
+def _decode_by_period(params, cache, tokens, pos, cfg, rt):
+    """The decode step as a plain loop: slice each period's parameters and
+    cache, run its layers with the per-layer decode functions, stack."""
+    from repro.models import transformer
+
+    x = params["embed"].value.astype(cfg.cdtype)[tokens]
+    caches = []
+    for j in range(cfg.n_periods):
+        pp = jax.tree_util.tree_map(lambda a: a[j], params["periods"])
+        pc = jax.tree_util.tree_map(lambda a: a[j], cache)
+        new = {}
+        for i, (mixer, ffn) in enumerate(cfg.period):
+            p, c = pp[f"l{i}"], pc[f"l{i}"]
+            if mixer == "attn":
+                x, c = blocks.attn_decode(p["mixer"], x, c, pos, cfg, rt)
+            elif mixer == "mamba":
+                x, c = ssm.mamba_decode(p["mixer"], x, c, cfg)
+            elif mixer == "mlstm":
+                x, c = xlstm.mlstm_decode(p["mixer"], x, c, cfg)
+            elif mixer == "slstm":
+                x, c = xlstm.slstm_decode(p["mixer"], x, c, cfg)
+            if ffn == "dense":
+                x = blocks.mlp_apply(p["ffn"], x, cfg, rt)
+            new[f"l{i}"] = c
+        caches.append(new)
+    h = common.rmsnorm(x, params["final_norm"].value)
+    logits = common.top1_logits(h[:, 0],
+                                transformer._out_embed(params, cfg))
+    return logits, jax.tree_util.tree_map(lambda *a: jnp.stack(a), *caches)
+
+
+def _decode_configs():
+    from repro.api.probes import serving_tiny_config
+
+    tiny, _ = serving_tiny_config()
+    common_kw = dict(n_layers=4, d_model=32, n_heads=4, n_kv_heads=2,
+                     d_ff=64, vocab_size=128, param_dtype="float32",
+                     compute_dtype="float32")
+    return {
+        "attn": tiny,
+        "attn+mamba": ModelConfig(name="hybrid", family="hybrid",
+                                  period=(("attn", "dense"),
+                                          ("mamba", "dense")), **common_kw),
+        "mlstm+slstm": ModelConfig(name="xlstm", family="ssm",
+                                   period=(("mlstm", "none"),
+                                           ("slstm", "none")), **common_kw),
+    }
+
+
+@pytest.mark.parametrize("per_row", [False, True], ids=["scalar", "per_row"])
+@pytest.mark.parametrize("arch", ["attn", "attn+mamba", "mlstm+slstm"])
+def test_decode_step_matches_per_period_loop(arch, per_row):
+    """The stacked cache carried through the scan and written in place gives
+    the logits and cache of a loop that slices each period out and stacks
+    the results, bit for bit, over consecutive steps; per-row positions
+    include a row at 0 and a row that ends at ``max_len - 1``."""
+    from repro.models import transformer
+
+    cfg = _decode_configs()[arch]
+    rt = Runtime(remat=False, xent_chunk=16, moe_groups=1)
+    b, max_len, steps = 4, 16, 3
+    params = transformer.init_lm(KEY, cfg)
+    cache = transformer.init_cache(cfg, b, max_len, cfg.cdtype)
+    leaves, tree = jax.tree_util.tree_flatten(cache)
+    keys = jax.random.split(jax.random.PRNGKey(11), len(leaves))
+    cache = jax.tree_util.tree_unflatten(tree, [
+        (jax.random.normal(k, a.shape) * 0.5).astype(a.dtype)
+        for k, a in zip(keys, leaves)])
+    start = (np.array([0, 3, 7, max_len - steps], np.int32) if per_row
+             else max_len - steps)
+    tokens = jax.random.randint(jax.random.PRNGKey(12), (steps, b, 1), 0,
+                                cfg.vocab_size)
+    step = jax.jit(lambda p, c, t, pos: transformer.decode_step(
+        p, c, t, pos, cfg, rt))
+    ref = jax.jit(lambda p, c, t, pos: _decode_by_period(p, c, t, pos, cfg,
+                                                         rt))
+    got_c, want_c = cache, cache
+    for t in range(steps):
+        pos = jnp.asarray(start + t, jnp.int32)
+        got, got_c = step(params, got_c, tokens[t], pos)
+        want, want_c = ref(params, want_c, tokens[t], pos)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        for g, w in zip(jax.tree_util.tree_leaves(got_c),
+                        jax.tree_util.tree_leaves(want_c)):
+            assert g.dtype == w.dtype
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

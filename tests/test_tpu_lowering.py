@@ -4,10 +4,12 @@ The Pallas interpreter accepts programs Mosaic refuses (unaligned slices,
 scalar loads from vector memory, 1-D vector layouts), so every kernel the
 characterization plans run on the chip is compiled here for a described
 v5e topology: nothing runs, but what the chip's compiler would refuse fails.
-The topology is described inside a fixture only, never at import time: one
-process at a time may load the TPU library.
+The serving decode step is compiled the same way, to check that it updates
+the KV cache in place. The topology is described inside a fixture only,
+never at import time: one process at a time may load the TPU library.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -86,3 +88,97 @@ def test_inkernel_chains_lower(one_chip, monkeypatch):
                                   interpret=False)
         assert "tpu_custom_call" in _compile_for_chip(
             fn, (carry,) + tuple(operands), one_chip), spec.name
+
+
+# ------------------------------------------------------- decode step, in place
+_INSTR = re.compile(
+    r"\s*(ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\((%[\w.\-]+)?")
+
+
+def _cache_movers(hlo: str, shapes: set) -> list:
+    """``(op, name)`` of each ``copy``, ``dynamic-slice`` and
+    ``dynamic-update-slice`` outside a fused computation whose result has
+    one of ``shapes``; a fusion counts as the op at its root, through
+    bitcasts."""
+    comps: dict = {}
+    cur = None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", line)
+        if head and line.rstrip().endswith("{"):
+            cur = comps.setdefault(head.group(1), {"instrs": {}, "root": None})
+            continue
+        m = _INSTR.match(line)
+        if cur is None or not m:
+            continue
+        root, name, dims, op, operand = m.groups()
+        called = re.search(r"calls=%([\w.\-]+)", line)
+        cur["instrs"][name] = (
+            tuple(int(d) for d in dims.split(",") if d), op,
+            operand[1:] if operand else None,
+            called.group(1) if called and op == "fusion" else None)
+        if root:
+            cur["root"] = name
+    # fusions with a tuple result are not parsed above, but call one too
+    fused = set(re.findall(r" fusion\(.*?calls=%([\w.\-]+)", hlo))
+
+    def root_op(comp: str) -> str:
+        c = comps[comp]
+        if c["root"] not in c["instrs"]:
+            return "tuple"
+        shape, op, operand, called = c["instrs"][c["root"]]
+        while op == "bitcast" and operand in c["instrs"]:
+            shape, op, operand, called = c["instrs"][operand]
+        return root_op(called) if called else op
+
+    found = []
+    for cname, c in comps.items():
+        if cname in fused:
+            continue
+        for name, (shape, op, _, called) in c["instrs"].items():
+            op = root_op(called) if called else op
+            if shape in shapes and op in ("copy", "dynamic-slice",
+                                          "dynamic-update-slice"):
+                found.append((op, name))
+    return found
+
+
+@pytest.mark.parametrize("per_row", [True, False], ids=["per_row", "scalar"])
+def test_decode_step_updates_the_stacked_cache_in_place(one_chip, per_row):
+    """The donated decode step writes each token into the stacked KV cache
+    in place, for per-row and for scalar positions: no period's K/V is
+    written back, the stack is not copied, and the only period-sized slices
+    are attention's reads (one per cache leaf), so the step's temporaries
+    stay below one period's K."""
+    from repro.models import transformer
+    from repro.models.config import ModelConfig, Runtime
+
+    periods, slots, max_len, kv_heads, hd = 4, 8, 2048, 4, 128
+    cfg = ModelConfig(name="guard", family="dense", n_layers=periods,
+                      d_model=1024, n_heads=8, n_kv_heads=kv_heads,
+                      head_dim=hd, d_ff=2048, vocab_size=1024,
+                      param_dtype="bfloat16", compute_dtype="bfloat16",
+                      tie_embeddings=False)
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            tree)
+
+    params = placed(jax.eval_shape(
+        lambda: transformer.init_lm(jax.random.PRNGKey(0), cfg)))
+    cache = placed(jax.eval_shape(
+        lambda: transformer.init_cache(cfg, slots, max_len, cfg.cdtype)))
+    tokens = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((slots,) if per_row else (), jnp.int32,
+                               sharding=one_chip)
+    step = jax.jit(lambda p, c, t, q: transformer.decode_step(
+        p, c, t, q, cfg, Runtime()), donate_argnums=(1,))
+    compiled = step.lower(params, cache, tokens, pos).compile()
+
+    period = (slots, max_len, kv_heads, hd)
+    period_bytes = slots * max_len * kv_heads * hd * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < period_bytes
+    movers = _cache_movers(compiled.as_text(),
+                           {period, (1,) + period, (periods,) + period})
+    assert [m for m in movers if m[0] != "dynamic-slice"] == []
+    assert len(movers) <= 2, movers
