@@ -10,8 +10,9 @@ from repro.kernels.chase import chase
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.flash_decode import flash_decode
 from repro.kernels.mamba_scan import mamba_scan
+from repro.kernels.moe_experts import moe_experts
 from repro.kernels.opchain import op_chain
 from repro.kernels.rmsnorm import rmsnorm
 
 __all__ = ["alu_chain", "chase", "flash_attention", "flash_decode",
-           "mamba_scan", "op_chain", "rmsnorm"]
+           "mamba_scan", "moe_experts", "op_chain", "rmsnorm"]

@@ -85,6 +85,18 @@ def ref_selective_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
     return y.astype(x.dtype), h
 
 
+# ------------------------------------------------------------ moe experts
+def ref_moe_experts(x: jax.Array, comb: jax.Array, wg: jax.Array,
+                    wu: jax.Array, wd: jax.Array) -> jax.Array:
+    """Every row through every expert, weighted: x [N,D]; comb [N,E];
+    wg, wu [E,D,F]; wd [E,F,D] -> [N,D] float32."""
+    xf = x.astype(jnp.float32)
+    g = jnp.einsum("nd,edf->enf", xf, wg.astype(jnp.float32))
+    u = jnp.einsum("nd,edf->enf", xf, wu.astype(jnp.float32))
+    o = jnp.einsum("enf,efd->end", jax.nn.silu(g) * u, wd.astype(jnp.float32))
+    return jnp.einsum("ne,end->nd", comb.astype(jnp.float32), o)
+
+
 # -------------------------------------------------------------- alu chain
 def ref_alu_chain(x: jax.Array, a: jax.Array, n: int) -> jax.Array:
     """Dependent fma chain oracle: x <- x*a + a, n times (f32 accumulate)."""

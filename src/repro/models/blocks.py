@@ -48,7 +48,7 @@ def _project_qkv(p: Params, x, cfg: ModelConfig, rt: Runtime | None = None):
 
 
 def _rope(cfg: ModelConfig, q, k, positions):
-    if positions is None:
+    if positions is None or cfg.pos_emb == "none":
         return q, k
     if cfg.mrope_sections is not None:
         q = common.apply_mrope(q, positions, cfg.mrope_sections, cfg.rope_theta)
@@ -79,7 +79,8 @@ def attn_train(p: Params, x, cfg: ModelConfig, rt: Runtime, positions,
     ``kv``: optional encoder memory for cross-attention (bidirectional).
     """
     with jax.named_scope("attn"):
-        h = common.rmsnorm(x, p["norm"].value) if cfg.norm == "rmsnorm" else x
+        h = (common.rmsnorm(x, p["norm"].value, cfg.norm_eps)
+             if cfg.norm == "rmsnorm" else x)
         src = h if kv is None else kv
         q = jnp.einsum("bsd,dhk->bshk", h, _w(p, "wq", cfg.cdtype, rt))
         k = jnp.einsum("bsd,dhk->bshk", src, _w(p, "wk", cfg.cdtype, rt))
@@ -121,7 +122,8 @@ def _attn_decode(p: Params, x, pos, cfg: ModelConfig, positions, write):
     token's K/V and returns the [B,Smax,KH,hd] views to attend over."""
     with jax.named_scope("attn"):
         b = x.shape[0]
-        h = common.rmsnorm(x, p["norm"].value) if cfg.norm == "rmsnorm" else x
+        h = (common.rmsnorm(x, p["norm"].value, cfg.norm_eps)
+             if cfg.norm == "rmsnorm" else x)
         q, k, v = _project_qkv(p, h, cfg)
         pos_arr = jnp.asarray(pos, jnp.int32)
         if positions is None:
@@ -192,7 +194,7 @@ def attn_decode_stacked(p: Params, x, cache: Params, period, pos,
 
 def attn_cross_decode(p: Params, x, mem_kv, cfg: ModelConfig):
     """Cross-attention decode step against precomputed encoder memory."""
-    h = common.rmsnorm(x, p["norm"].value)
+    h = common.rmsnorm(x, p["norm"].value, cfg.norm_eps)
     q = jnp.einsum("bsd,dhk->bshk", h, p["wq"].value.astype(cfg.cdtype))
     k, v = mem_kv
     out = common.decode_attention(q[:, 0], k, v, kv_len=k.shape[1])
@@ -214,7 +216,7 @@ def init_mlp(key, cfg: ModelConfig, d_ff: int | None = None) -> Params:
 
 def mlp_apply(p: Params, x, cfg: ModelConfig, rt: Runtime | None = None):
     with jax.named_scope("mlp"):
-        h = common.rmsnorm(x, p["norm"].value)
+        h = common.rmsnorm(x, p["norm"].value, cfg.norm_eps)
         cd = cfg.cdtype
         g = jnp.einsum("bsd,df->bsf", h, _w(p, "wg", cd, rt))
         u = jnp.einsum("bsd,df->bsf", h, _w(p, "wu", cd, rt))
@@ -225,11 +227,13 @@ def mlp_apply(p: Params, x, cfg: ModelConfig, rt: Runtime | None = None):
 
 # ================================================================= MoE block
 def init_moe(key, cfg: ModelConfig) -> Params:
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.n_experts
+    """The router over all ``n_experts``; weights of the held ones only."""
+    d, f, e = cfg.d_model, cfg.d_ff, cfg.n_held
     ks = jax.random.split(key, 5)
     p = {
         "norm": Param(jnp.ones((d,), cfg.pdtype), ("embed",)),
-        "router": common.dense_param(ks[0], d, e, ("embed", None), cfg.pdtype),
+        "router": common.dense_param(ks[0], d, cfg.n_experts, ("embed", None),
+                                     cfg.pdtype),
         "wg": common.dense_param(ks[1], d, f, ("experts", "embed", "expert_mlp"),
                                  cfg.pdtype, shape=(e, d, f)),
         "wu": common.dense_param(ks[2], d, f, ("experts", "embed", "expert_mlp"),
@@ -261,28 +265,125 @@ def _dispatch_indices(expert_idx: jax.Array, n_experts: int, capacity: int):
     return slot
 
 
-def moe_apply(p: Params, x, cfg: ModelConfig, rt: Runtime):
-    """Token-choice top-k MoE with sort-based capacity dispatch.
+def _route(p: Params, h, cfg: ModelConfig):
+    """The router at its published width, over all ``n_experts``: softmax,
+    then the top ``top_k``, renormalized only where the configuration says.
+    h: [..., D] -> (gates [..., E], weights and experts [..., k])."""
+    with jax.named_scope("router"):
+        logits = jnp.einsum("...d,de->...e", h.astype(jnp.float32),
+                            p["router"].value.astype(jnp.float32))
+        gates = jax.nn.softmax(logits, axis=-1)
+        top_w, top_e = lax.top_k(gates, cfg.top_k)
+        if cfg.moe_renormalize:
+            top_w = top_w / jnp.maximum(jnp.sum(top_w, -1, keepdims=True), 1e-9)
+    return gates, top_w, top_e
 
-    Tokens are regrouped as [G, N/G] with G == data shards so routing stays
-    shard-local; the dispatch scatter across the expert-sharded buffer is the
-    EP boundary (GSPMD emits the all-to-all/all-gather there).
+
+def moe_apply(p: Params, x, cfg: ModelConfig, rt: Runtime):
+    """Token-choice top-k MoE: dropless over the held experts
+    (``cfg.moe_dropless``) or with sort-based capacity dispatch.
+
+    For capacity dispatch, tokens are regrouped as [G, N/G] with G == data
+    shards so routing stays shard-local; the dispatch scatter across the
+    expert-sharded buffer is the EP boundary (GSPMD emits the
+    all-to-all/all-gather there).
     """
     b, s, d = x.shape
+    with jax.named_scope("moe"):
+        h = common.rmsnorm(x, p["norm"].value, cfg.norm_eps)
+        n_tok = b * s
+        if cfg.moe_dropless:
+            gates, top_w, top_e = _route(p, h.reshape(n_tok, d), cfg)
+            y = _experts_dropless(p, h.reshape(n_tok, d), top_w, top_e,
+                                  cfg).reshape(b, s, d)
+            top_e = top_e[None]
+            gates = gates[None]
+        else:
+            if rt.moe_gather_decode and n_tok <= 256:
+                return _moe_gather_few_tokens(p, x, h, cfg)
+            y, gates, top_e = _experts_capacity(p, h, cfg, rt)
+        if "shared" in p:
+            # shared expert runs densely on all tokens; reuse mlp without
+            # residual
+            y = y + (mlp_apply(p["shared"], x, cfg) - x)
+        aux = _load_balance_loss(gates, top_e, cfg.n_experts)
+        return x + annotate(y, "batch", "seq", None), aux
+
+
+# A step of at most this many tokens sends every one through every held
+# expert (the ``moe_experts`` kernel): below about 240 rows on a v5e (197
+# TFLOP/s over 819 GB/s) that pass is bound by reading the experts' weights,
+# which the grouped matmul reads as well. Decode steps fall here, prefills
+# above.
+DENSE_ROWS = 64
+
+
+def _experts_dropless(p: Params, h, top_w, top_e, cfg: ModelConfig):
+    """The held experts' part of a dropless layer, every routed token
+    computed. h: [N,D]; top_w, top_e: [N,k] over all ``n_experts``.
+
+    Each held expert's weights are read once: a few tokens go through every
+    held expert, weighted by their combine weight; more go, sorted by
+    expert, through a grouped matmul over the held experts."""
+    n, d = h.shape
+    lo, hi = cfg.held
+    cd = cfg.cdtype
+    wg, wu, wd = (p[w].value.astype(cd) for w in ("wg", "wu", "wd"))
+    with jax.named_scope("experts"):
+        if n <= DENSE_ROWS:
+            from repro.kernels.moe_experts import moe_experts
+            held = jnp.arange(lo, hi)
+            comb = jnp.sum(jnp.where(top_e[..., None] == held, top_w[..., None],
+                                     0.0), axis=1)               # [N,E_held]
+            return moe_experts(h.astype(cd), comb, wg, wu, wd).astype(cd)
+        k = cfg.top_k
+        local = top_e.reshape(-1) - lo                           # [N*k]
+        held = (local >= 0) & (local < hi - lo)
+        group = jnp.where(held, local, hi - lo)    # other experts sort last
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.zeros((hi - lo + 1,), jnp.int32).at[group].add(1)[:-1]
+        tok = order // k
+        xs = h.astype(cd)[tok]
+        g = _grouped(xs, wg, sizes)
+        u = _grouped(xs, wu, sizes)
+        eo = _grouped(jax.nn.silu(g) * u, wd, sizes)
+        # rows routed to experts held elsewhere are in no group: whatever
+        # the grouped matmul left there is masked out
+        w = jnp.where(held, top_w.reshape(-1), 0.0)[order]
+        eo = jnp.where(held[order][:, None], eo.astype(jnp.float32), 0.0)
+        y = jnp.zeros((n, d), jnp.float32).at[tok].add(eo * w[:, None])
+        return y.astype(cd)
+
+
+def _grouped(x, w, sizes):
+    """Rows of ``x`` sorted by group times their group's matrix: x [M,K];
+    w [G,K,N]; sizes [G] rows a group, in order. Megablox's grouped matmul
+    (``gmm``), which on a v5e outran ``lax.ragged_dot`` at Jamba's prefill
+    shapes (4.79 against 5.66 ms at 6,144 rows); rows past the groups are
+    left undefined."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    from repro.kernels.common import use_interpret
+
+    m, kk = x.shape
+    nn = w.shape[2]
+    tm = min(512, -(-m // 128) * 128)
+    xp = jnp.pad(x, ((0, -m % tm), (0, 0)))
+    out = gmm(xp, w, sizes, x.dtype, (tm, min(1024, kk), min(1024, nn)),
+              None, None, False, use_interpret())
+    return out[:m]
+
+
+def _experts_capacity(p: Params, h, cfg: ModelConfig, rt: Runtime):
+    """Every expert's part under capacity dispatch: tokens past an expert's
+    capacity are dropped. h: [B,S,D] -> (y, gates, top_e)."""
+    b, s, d = h.shape
     e, k, cd = cfg.n_experts, cfg.top_k, cfg.cdtype
-    h = common.rmsnorm(x, p["norm"].value)
     n_tok = b * s
-    if rt.moe_gather_decode and n_tok <= 256:
-        return _moe_gather_few_tokens(p, x, h, cfg)
     g = rt.moe_groups if n_tok % max(rt.moe_groups, 1) == 0 else 1
     ng = n_tok // g
     xt = annotate(h.reshape(g, ng, d), "batch", None, None)
-
-    logits = jnp.einsum("gnd,de->gne", xt.astype(jnp.float32),
-                        p["router"].value.astype(jnp.float32))
-    gates = jax.nn.softmax(logits, axis=-1)
-    top_w, top_e = lax.top_k(gates, k)                             # [G,N,k]
-    top_w = top_w / jnp.maximum(jnp.sum(top_w, -1, keepdims=True), 1e-9)
+    gates, top_w, top_e = _route(p, xt, cfg)                       # [G,N,k]
 
     cap = max(int(cfg.capacity_factor * ng / e) // 8 * 8, 8)
     gi = jnp.arange(g)[:, None]
@@ -307,13 +408,7 @@ def moe_apply(p: Params, x, cfg: ModelConfig, rt: Runtime):
             [eout.reshape(g, e * cap, d), jnp.zeros((g, 1, d), cd)], axis=1)
         gathered = jnp.take_along_axis(flat, slot[..., None], axis=1)   # [G,N,D]
         out = out + gathered * top_w[..., slot_k, None].astype(cd)
-
-    y = out.reshape(b, s, d)
-    if "shared" in p:
-        # shared expert runs densely on all tokens; reuse mlp without residual
-        y = y + (mlp_apply(p["shared"], x, cfg) - x)
-    aux = _load_balance_loss(gates, top_e, e)
-    return x + annotate(y, "batch", "seq", None), aux
+    return out.reshape(b, s, d), gates, top_e
 
 
 def _moe_gather_few_tokens(p: Params, x, h, cfg: ModelConfig):
@@ -326,10 +421,7 @@ def _moe_gather_few_tokens(p: Params, x, h, cfg: ModelConfig):
     b, s, d = x.shape
     k, cd = cfg.top_k, cfg.cdtype
     hf = h.reshape(b * s, d)
-    logits = jnp.einsum("nd,de->ne", hf.astype(jnp.float32),
-                        p["router"].value.astype(jnp.float32))
-    top_w, top_e = lax.top_k(jax.nn.softmax(logits, -1), k)      # [N,k]
-    top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
+    _, top_w, top_e = _route(p, hf, cfg)                          # [N,k]
     wg = p["wg"].value[top_e]        # [N,k,D,F] gathered slices
     wu = p["wu"].value[top_e]
     wd = p["wd"].value[top_e]

@@ -34,16 +34,21 @@ class ModelConfig:
     head_dim: int = 0
     period: tuple[Layer, ...] = (("attn", "dense"),)
     # --- MoE ---
-    n_experts: int = 0
+    n_experts: int = 0           # the router's width: experts routed over
     top_k: int = 1
     capacity_factor: float = 1.25
     shared_expert: bool = False
+    moe_renormalize: bool = True  # top-k weights rescaled to sum to 1
+    moe_dropless: bool = False   # every routed token computed (no capacity)
+    experts_held: tuple[int, int] | None = None  # [lo, hi) held here; None = all
     # --- SSM (mamba) ---
     ssm_state: int = 16
     ssm_conv: int = 4
     ssm_expand: int = 2
     dt_rank: int = 0             # 0 -> ceil(d_model/16)
+    ssm_dbc_norm: bool = False   # RMSNorm on dt, B and C after x_proj (Jamba)
     # --- positions ---
+    pos_emb: str = "rope"        # rope | none
     rope_theta: float = 1e4
     mrope_sections: tuple[int, int, int] | None = None
     # --- enc-dec (audio/seq2seq backbones) ---
@@ -66,6 +71,12 @@ class ModelConfig:
             f"{self.name}: n_layers {self.n_layers} % period {len(self.period)}"
         for mixer, ffn in self.period:
             assert mixer in MIXERS and ffn in FFNS, (mixer, ffn)
+        assert self.pos_emb in ("rope", "none"), self.pos_emb
+        if self.experts_held is not None:
+            lo, hi = self.experts_held
+            assert self.moe_dropless and 0 <= lo < hi <= self.n_experts, \
+                f"{self.name}: experts_held {self.experts_held} needs the " \
+                f"dropless layer and a range of the {self.n_experts} experts"
 
     # ------------------------------------------------------------- derived
     @property
@@ -79,6 +90,16 @@ class ModelConfig:
     @property
     def dt_r(self) -> int:
         return self.dt_rank or -(-self.d_model // 16)
+
+    @property
+    def held(self) -> tuple[int, int]:
+        """``[lo, hi)``: the experts this layer holds and computes."""
+        return self.experts_held or (0, self.n_experts)
+
+    @property
+    def n_held(self) -> int:
+        lo, hi = self.held
+        return hi - lo
 
     @property
     def ssm_inner(self) -> int:
@@ -114,6 +135,8 @@ class ModelConfig:
         mamba = (d + d * 2 * di + di * self.ssm_conv + di
                  + di * (dtr + 2 * n) + dtr * di + di
                  + di * n + di + di * d)
+        if self.ssm_dbc_norm:
+            mamba += dtr + 2 * n
         mlstm = (d + d * 2 * di + 5 * di + 3 * di * di
                  + 2 * di * self.n_heads + di * d)
         slstm = d + 4 * d * d + 4 * d * dh + 4 * d + d + d * d
@@ -125,7 +148,7 @@ class ModelConfig:
                 total += dense_ffn
                 active += dense_ffn
             elif ffn == "moe":
-                total += d + d * self.n_experts + self.n_experts * expert_ffn
+                total += d + d * self.n_experts + self.n_held * expert_ffn
                 active += d + d * self.n_experts + self.top_k * expert_ffn
                 if self.shared_expert:
                     total += dense_ffn

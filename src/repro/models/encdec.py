@@ -60,7 +60,7 @@ def encode(params: Params, cfg: ModelConfig, rt: Runtime, frames: jax.Array):
 
     body_fn = jax.checkpoint(body) if rt.remat else body
     x, _ = lax.scan(body_fn, x, params["encoder"])
-    return common.rmsnorm(x, params["enc_norm"].value)
+    return common.rmsnorm(x, params["enc_norm"].value, cfg.norm_eps)
 
 
 def decode_train(params: Params, cfg: ModelConfig, rt: Runtime, memory,
@@ -79,7 +79,7 @@ def decode_train(params: Params, cfg: ModelConfig, rt: Runtime, memory,
 
     body_fn = jax.checkpoint(body) if rt.remat else body
     x, caches = lax.scan(body_fn, x, params["decoder"])
-    return common.rmsnorm(x, params["final_norm"].value), caches
+    return common.rmsnorm(x, params["final_norm"].value, cfg.norm_eps), caches
 
 
 def train_loss(params: Params, batch: dict, cfg: ModelConfig, rt: Runtime):
@@ -125,6 +125,6 @@ def decode_step(params: Params, cache: Params, tokens, pos, cfg: ModelConfig,
                    "ck": lc["ck"], "cv": lc["cv"]}
 
     x, new_cache = lax.scan(body, x, (params["decoder"], cache))
-    h = common.rmsnorm(x, params["final_norm"].value)
+    h = common.rmsnorm(x, params["final_norm"].value, cfg.norm_eps)
     logits = common.top1_logits(h[:, 0], params["embed"].value)
     return logits, new_cache

@@ -25,7 +25,7 @@ Params = dict[str, Any]
 def init_mamba(key, cfg: ModelConfig) -> Params:
     d, di, n, k, dtr = cfg.d_model, cfg.ssm_inner, cfg.ssm_state, cfg.ssm_conv, cfg.dt_r
     ks = jax.random.split(key, 7)
-    return {
+    p = {
         "norm": Param(jnp.ones((d,), cfg.pdtype), ("embed",)),
         "in_proj": common.dense_param(ks[0], d, 2 * di, ("embed", "ssm_inner"), cfg.pdtype),
         "conv_w": Param(common.trunc_normal(ks[1], (di, k), (1.0 / k) ** 0.5, cfg.pdtype),
@@ -40,6 +40,10 @@ def init_mamba(key, cfg: ModelConfig) -> Params:
         "d_skip": Param(jnp.ones((di,), cfg.pdtype), ("ssm_inner",)),
         "out_proj": common.dense_param(ks[4], di, d, ("ssm_inner", "embed"), cfg.pdtype),
     }
+    if cfg.ssm_dbc_norm:
+        for name, width in (("dt_norm", dtr), ("b_norm", n), ("c_norm", n)):
+            p[name] = Param(jnp.ones((width,), cfg.pdtype), (None,))
+    return p
 
 
 def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
@@ -62,11 +66,16 @@ def _ssm_inputs(p: Params, h, cfg: ModelConfig):
 
 
 def _ssm_params(p: Params, x1, cfg: ModelConfig):
-    """Input-dependent dt/B/C from conv'd activations (f32 for the scan)."""
+    """Input-dependent dt/B/C from conv'd activations (f32 for the scan),
+    each RMS-normed first where ``cfg.ssm_dbc_norm`` (Jamba)."""
     cd = cfg.cdtype
     n, dtr = cfg.ssm_state, cfg.dt_r
     dbc = jnp.einsum("bsi,ie->bse", x1, p["x_proj"].value.astype(cd))
     dt_r, b_in, c_in = jnp.split(dbc, [dtr, dtr + n], axis=-1)
+    if cfg.ssm_dbc_norm:
+        dt_r, b_in, c_in = (common.rmsnorm(t, p[name].value, cfg.norm_eps)
+                            for t, name in ((dt_r, "dt_norm"), (b_in, "b_norm"),
+                                            (c_in, "c_norm")))
     dt = jnp.einsum("bsr,ri->bsi", dt_r, p["dt_w"].value.astype(cd))
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_b"].value.astype(jnp.float32))
     a = -jnp.exp(p["a_log"].value.astype(jnp.float32))          # [Di,N]
@@ -74,18 +83,18 @@ def _ssm_params(p: Params, x1, cfg: ModelConfig):
 
 
 def _chunk_scan(dt, a, b_in, c_in, x1, chunk: int):
-    """Chunked associative scan. Shapes: dt,x1 [B,S,Di]; b,c [B,S,N]."""
+    """Chunked associative scan. Shapes: dt,x1 [B,S,Di]; b,c [B,S,N];
+    y comes back in ``x1``'s type.
+
+    Each chunk's ``[B,Lc,Di,N]`` decay and input tiles are built inside the
+    chunk loop from its ``[B,Lc,...]`` slices, so no ``[B,S,Di,N]`` tensor
+    exists (at a 3,072-token prompt and Di 8,192 one would be 1.6 GB)."""
     bsz, s, di = x1.shape
-    n = a.shape[1]
-    from repro.models.common import fit_chunk
-    lc = fit_chunk(s, chunk)
+    lc = common.fit_chunk(s, chunk)
     nc = s // lc
-    xf = x1.astype(jnp.float32)
-    da = jnp.exp(dt[..., None] * a[None, None])                  # [B,S,Di,N]
-    u = (dt * xf)[..., None] * b_in[:, :, None, :]               # [B,S,Di,N]
-    da_c = da.reshape(bsz, nc, lc, di, n)
-    u_c = u.reshape(bsz, nc, lc, di, n)
-    c_c = c_in.reshape(bsz, nc, lc, n)
+
+    def chunks(t):                                   # [B,S,...] -> [nc,B,Lc,...]
+        return jnp.moveaxis(t.reshape(bsz, nc, lc, *t.shape[2:]), 1, 0)
 
     def combine(left, right):
         a1, u1 = left
@@ -93,42 +102,50 @@ def _chunk_scan(dt, a, b_in, c_in, x1, chunk: int):
         return a1 * a2, a2 * u1 + u2
 
     def chunk_step(h, xs):
-        da_k, u_k, c_k = xs                                      # [B,Lc,Di,N]
+        dt_k, x_k, b_k, c_k = xs                     # [B,Lc,Di], [B,Lc,N]
+        da_k = jnp.exp(dt_k[..., None] * a)          # [B,Lc,Di,N]
+        u_k = (dt_k * x_k)[..., None] * b_k[:, :, None, :]
         u0 = u_k.at[:, 0].add(da_k[:, 0] * h)
-        acc_a, acc_u = lax.associative_scan(combine, (da_k, u0), axis=1)
+        _, acc_u = lax.associative_scan(combine, (da_k, u0), axis=1)
         y_k = jnp.einsum("bldn,bln->bld", acc_u, c_k)
-        return acc_u[:, -1], y_k
+        return acc_u[:, -1], y_k.astype(x1.dtype)
 
-    h0 = jnp.zeros((bsz, di, n), jnp.float32)
+    h0 = jnp.zeros((bsz, di, a.shape[1]), jnp.float32)
     h_final, y = lax.scan(chunk_step, h0,
-                          (jnp.moveaxis(da_c, 1, 0), jnp.moveaxis(u_c, 1, 0),
-                           jnp.moveaxis(c_c, 1, 0)))
+                          (chunks(dt), chunks(x1.astype(jnp.float32)),
+                           chunks(b_in), chunks(c_in)))
     y = jnp.moveaxis(y, 0, 1).reshape(bsz, s, di)
     return y, h_final
 
 
 def mamba_train(p: Params, x, cfg: ModelConfig, rt: Runtime):
     """x: [B,S,D] -> (residual output, decode cache {h, conv})."""
-    h = common.rmsnorm(x, p["norm"].value)
-    x1, z = _ssm_inputs(p, h, cfg)
-    conv_tail = x1[:, -(cfg.ssm_conv - 1):]         # pre-conv inputs for decode
-    x1 = jax.nn.silu(_causal_conv(x1, p["conv_w"].value.astype(cfg.cdtype),
-                                  p["conv_b"].value.astype(cfg.cdtype)))
-    dt, a, b_in, c_in = _ssm_params(p, x1, cfg)
-    if rt.use_pallas:
-        from repro.kernels.ops import mamba_scan
-        # kernel consumes raw dt (applies softplus itself); pass pre-softplus
-        y = mamba_scan(x1.astype(jnp.float32),
-                       jnp.log(jnp.expm1(jnp.maximum(dt, 1e-6))), a, b_in, c_in,
-                       p["d_skip"].value.astype(jnp.float32), chunk=rt.mamba_chunk)
-        h_final = jnp.zeros((x.shape[0], cfg.ssm_inner, cfg.ssm_state), jnp.float32)
-    else:
-        y, h_final = _chunk_scan(dt, a, b_in, c_in, x1, rt.mamba_chunk)
-        y = y + x1.astype(jnp.float32) * p["d_skip"].value.astype(jnp.float32)
-    y = (y.astype(cfg.cdtype) * jax.nn.silu(z))
-    out = jnp.einsum("bsi,id->bsd", y, p["out_proj"].value.astype(cfg.cdtype))
-    cache = {"h": h_final, "conv": conv_tail.astype(cfg.cdtype)}
-    return x + annotate(out, "batch", "seq", None), cache
+    with jax.named_scope("mamba"):
+        h = common.rmsnorm(x, p["norm"].value, cfg.norm_eps)
+        x1, z = _ssm_inputs(p, h, cfg)
+        conv_tail = x1[:, -(cfg.ssm_conv - 1):]   # pre-conv inputs for decode
+        x1 = jax.nn.silu(_causal_conv(
+            x1, p["conv_w"].value.astype(cfg.cdtype),
+            p["conv_b"].value.astype(cfg.cdtype)))
+        dt, a, b_in, c_in = _ssm_params(p, x1, cfg)
+        if rt.use_pallas:
+            from repro.kernels.ops import mamba_scan
+            # kernel consumes raw dt (applies softplus itself); pass
+            # pre-softplus
+            y, h_final = mamba_scan(
+                x1.astype(jnp.float32),
+                jnp.log(jnp.expm1(jnp.maximum(dt, 1e-6))), a, b_in, c_in,
+                p["d_skip"].value.astype(jnp.float32),
+                chunk=rt.mamba_chunk, return_state=True)
+        else:
+            y, h_final = _chunk_scan(dt, a, b_in, c_in, x1, rt.mamba_chunk)
+            y = y + x1.astype(jnp.float32) * p["d_skip"].value.astype(
+                jnp.float32)
+        y = (y.astype(cfg.cdtype) * jax.nn.silu(z))
+        out = jnp.einsum("bsi,id->bsd", y,
+                         p["out_proj"].value.astype(cfg.cdtype))
+        cache = {"h": h_final, "conv": conv_tail.astype(cfg.cdtype)}
+        return x + annotate(out, "batch", "seq", None), cache
 
 
 def init_mamba_cache(cfg: ModelConfig, batch: int, dtype) -> Params:
@@ -140,20 +157,25 @@ def init_mamba_cache(cfg: ModelConfig, batch: int, dtype) -> Params:
 
 def mamba_decode(p: Params, x, cache: Params, cfg: ModelConfig):
     """One-token step. x: [B,1,D]."""
-    cd = cfg.cdtype
-    h = common.rmsnorm(x, p["norm"].value)
-    x1, z = _ssm_inputs(p, h, cfg)                                # [B,1,Di]
-    w = p["conv_w"].value.astype(cd)                              # [Di,K]
-    hist = jnp.concatenate([cache["conv"], x1.astype(cache["conv"].dtype)], axis=1)
-    conv = jnp.einsum("bki,ik->bi", hist.astype(cd), w) + p["conv_b"].value.astype(cd)
-    x1s = jax.nn.silu(conv)[:, None]                              # [B,1,Di]
-    dt, a, b_in, c_in = _ssm_params(p, x1s, cfg)
-    dtq = dt[:, 0]                                                # [B,Di]
-    da = jnp.exp(dtq[..., None] * a[None])                        # [B,Di,N]
-    hn = da * cache["h"] + (dtq * x1s[:, 0].astype(jnp.float32))[..., None] \
-        * b_in[:, 0, None, :]
-    y = jnp.einsum("bdn,bn->bd", hn, c_in[:, 0]) \
-        + x1s[:, 0].astype(jnp.float32) * p["d_skip"].value.astype(jnp.float32)
-    y = (y.astype(cd) * jax.nn.silu(z[:, 0]))[:, None]
-    out = jnp.einsum("bsi,id->bsd", y, p["out_proj"].value.astype(cd))
-    return x + out, {"h": hn, "conv": hist[:, 1:]}
+    with jax.named_scope("mamba"):
+        cd = cfg.cdtype
+        h = common.rmsnorm(x, p["norm"].value, cfg.norm_eps)
+        x1, z = _ssm_inputs(p, h, cfg)                            # [B,1,Di]
+        w = p["conv_w"].value.astype(cd)                          # [Di,K]
+        hist = jnp.concatenate([cache["conv"],
+                                x1.astype(cache["conv"].dtype)], axis=1)
+        conv = (jnp.einsum("bki,ik->bi", hist.astype(cd), w)
+                + p["conv_b"].value.astype(cd))
+        x1s = jax.nn.silu(conv)[:, None]                          # [B,1,Di]
+        dt, a, b_in, c_in = _ssm_params(p, x1s, cfg)
+        dtq = dt[:, 0]                                            # [B,Di]
+        da = jnp.exp(dtq[..., None] * a[None])                    # [B,Di,N]
+        hn = da * cache["h"] + (dtq * x1s[:, 0].astype(jnp.float32))[
+            ..., None] * b_in[:, 0, None, :]
+        y = (jnp.einsum("bdn,bn->bd", hn, c_in[:, 0])
+             + x1s[:, 0].astype(jnp.float32)
+             * p["d_skip"].value.astype(jnp.float32))
+        y = (y.astype(cd) * jax.nn.silu(z[:, 0]))[:, None]
+        out = jnp.einsum("bsi,id->bsd", y, p["out_proj"].value.astype(cd))
+        return x + out, {"h": hn, "conv": hist[:, 1:]}
+

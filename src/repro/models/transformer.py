@@ -193,7 +193,7 @@ def forward(params: Params, cfg: ModelConfig, rt: Runtime, *, tokens=None,
                 lambda *xs: jnp.stack(xs), *caches_list) \
                 if want_cache and caches_list else {}
     with jax.named_scope("head"):
-        h = common.rmsnorm(x, params["final_norm"].value)
+        h = common.rmsnorm(x, params["final_norm"].value, cfg.norm_eps)
     return h, aux, caches
 
 
@@ -274,10 +274,20 @@ def decode_step(params: Params, cache: Params, tokens, pos, cfg: ModelConfig,
         return (x, cache), None
 
     with jax.named_scope("layers"):
-        (x, new_cache), _ = lax.scan(
-            body, (x, cache),
-            (params["periods"], jnp.arange(cfg.n_periods, dtype=jnp.int32)))
+        if rt.scan_layers:
+            (x, new_cache), _ = lax.scan(
+                body, (x, cache),
+                (params["periods"], jnp.arange(cfg.n_periods, dtype=jnp.int32)))
+        else:
+            # periods unrolled, each indexed statically: a kernel's weight
+            # operand is then the stacked leaf itself, not a sliced copy
+            carry = (x, cache)
+            for i in range(cfg.n_periods):
+                pp = jax.tree_util.tree_map(lambda a, i=i: a[i],
+                                            params["periods"])
+                carry, _ = body(carry, (pp, i))
+            x, new_cache = carry
     with jax.named_scope("head"):
-        h = common.rmsnorm(x, params["final_norm"].value)
+        h = common.rmsnorm(x, params["final_norm"].value, cfg.norm_eps)
         logits = common.top1_logits(h[:, 0], _out_embed(params, cfg))
     return logits, new_cache

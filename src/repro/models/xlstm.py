@@ -46,7 +46,7 @@ def init_mlstm(key, cfg: ModelConfig) -> Params:
 
 def _mlstm_qkvif(p: Params, x, cfg: ModelConfig):
     cd = cfg.cdtype
-    h = common.rmsnorm(x, p["norm"].value)
+    h = common.rmsnorm(x, p["norm"].value, cfg.norm_eps)
     up = jnp.einsum("bsd,de->bse", h, p["up"].value.astype(cd))
     xm, z = jnp.split(up, 2, axis=-1)                       # [B,S,Di]
     xm = annotate(xm, "batch", "seq", "act_mlp")
@@ -120,7 +120,8 @@ def _mlstm_chunked(q, k, v, ig, fg, chunk: int):
 def mlstm_train(p: Params, x, cfg: ModelConfig, rt: Runtime):
     q, k, v, ig, fg, z, xm = _mlstm_qkvif(p, x, cfg)
     h, (cf, nf) = _mlstm_chunked(q, k, v, ig, fg, rt.mlstm_chunk)
-    h = common.rmsnorm(h.astype(cfg.cdtype), p["gn"].value) * jax.nn.silu(z)
+    h = (common.rmsnorm(h.astype(cfg.cdtype), p["gn"].value, cfg.norm_eps)
+         * jax.nn.silu(z))
     out = jnp.einsum("bsi,id->bsd", h, p["down"].value.astype(cfg.cdtype))
     cache = {"c": cf, "n": nf, "m": jnp.zeros(cf.shape[:2], jnp.float32),
              "conv": xm[:, -3:].astype(jnp.float32)}
@@ -142,7 +143,7 @@ def init_mlstm_cache(cfg: ModelConfig, batch: int) -> Params:
 def mlstm_decode(p: Params, x, cache: Params, cfg: ModelConfig):
     """Exact stabilized recurrence (one step). x: [B,1,D]."""
     cd = cfg.cdtype
-    hN = common.rmsnorm(x, p["norm"].value)
+    hN = common.rmsnorm(x, p["norm"].value, cfg.norm_eps)
     up = jnp.einsum("bsd,de->bse", hN, p["up"].value.astype(cd))
     xm, z = jnp.split(up, 2, axis=-1)
     hist = jnp.concatenate([cache["conv"], xm[:, 0][:, None].astype(jnp.float32)], axis=1)
@@ -166,7 +167,7 @@ def mlstm_decode(p: Params, x, cache: Params, cfg: ModelConfig):
     num = jnp.einsum("bhd,bhde->bhe", q, c_new)
     den = jnp.maximum(jnp.abs(jnp.einsum("bhd,bhd->bh", q, n_new)), jnp.exp(-m_new))
     h = (num / den[..., None]).reshape(b, di)
-    h = common.rmsnorm(h.astype(cd), p["gn"].value) * jax.nn.silu(z[:, 0])
+    h = common.rmsnorm(h.astype(cd), p["gn"].value, cfg.norm_eps) * jax.nn.silu(z[:, 0])
     out = (h @ p["down"].value.astype(cd))[:, None]
     return x + out, {"c": c_new, "n": n_new, "m": m_new, "conv": hist[:, 1:]}
 
@@ -213,7 +214,7 @@ def slstm_train(p: Params, x, cfg: ModelConfig, rt: Runtime):
     b, s, d = x.shape
     nh = cfg.n_heads
     dh = d // nh
-    hN = common.rmsnorm(x, p["norm"].value)
+    hN = common.rmsnorm(x, p["norm"].value, cfg.norm_eps)
     wx = (jnp.einsum("bsd,de->bse", hN, p["w"].value.astype(cd))
           + p["b"].value.astype(cd)).astype(jnp.float32)
     r = p["r"].value.astype(jnp.float32)
@@ -226,7 +227,7 @@ def slstm_train(p: Params, x, cfg: ModelConfig, rt: Runtime):
     init = (z, z, z, jnp.full((b, d), -1e30, jnp.float32))
     (c, n, hS, m), hs = lax.scan(step, init, jnp.moveaxis(wx, 1, 0))
     h = jnp.moveaxis(hs, 0, 1).astype(cd)                      # [B,S,D]
-    h = common.rmsnorm(h, p["gn"].value)
+    h = common.rmsnorm(h, p["gn"].value, cfg.norm_eps)
     out = jnp.einsum("bsd,de->bse", h, p["out"].value.astype(cd))
     cache = {"c": c, "n": n, "h": hS, "m": m}
     return x + annotate(out, "batch", "seq", None), cache
@@ -243,11 +244,11 @@ def slstm_decode(p: Params, x, cache: Params, cfg: ModelConfig):
     nh = cfg.n_heads
     d = cfg.d_model
     dh = d // nh
-    hN = common.rmsnorm(x, p["norm"].value)
+    hN = common.rmsnorm(x, p["norm"].value, cfg.norm_eps)
     wx = (jnp.einsum("bsd,de->bse", hN, p["w"].value.astype(cd))
           + p["b"].value.astype(cd)).astype(jnp.float32)[:, 0]
     state = (cache["c"], cache["n"], cache["h"], cache["m"])
     c, n, h, m = _slstm_cell(wx, state, p["r"].value.astype(jnp.float32), nh, dh)
-    hx = common.rmsnorm(h.astype(cd), p["gn"].value)
+    hx = common.rmsnorm(h.astype(cd), p["gn"].value, cfg.norm_eps)
     out = (hx @ p["out"].value.astype(cd))[:, None]
     return x + out, {"c": c, "n": n, "h": h, "m": m}

@@ -102,3 +102,35 @@ def test_mamba_scan(b, s, dm, n, chunk):
     y = ops.mamba_scan(x, dt, A, B, C, D, chunk=chunk, interpret=True)
     want, _ = ref.ref_selective_scan(x, dt, A, B, C, D)
     np.testing.assert_allclose(y, want, atol=5e-5, rtol=5e-5)
+
+
+@pytest.mark.parametrize("b,s,dm,n,chunk", [(2, 64, 16, 8, 16), (1, 96, 8, 4, 32)])
+def test_mamba_scan_returns_its_final_state(b, s, dm, n, chunk):
+    ks = jax.random.split(KEY, 6)
+    x = jax.random.normal(ks[0], (b, s, dm)) * 0.5
+    dt = jax.random.normal(ks[1], (b, s, dm)) * 0.1
+    A = -jnp.exp(jax.random.normal(ks[2], (dm, n)) * 0.3)
+    B = jax.random.normal(ks[3], (b, s, n)) * 0.5
+    C = jax.random.normal(ks[4], (b, s, n)) * 0.5
+    D = jax.random.normal(ks[5], (dm,)) * 0.1
+    y, h = ops.mamba_scan(x, dt, A, B, C, D, chunk=chunk, interpret=True,
+                          return_state=True)
+    want_y, want_h = ref.ref_selective_scan(x, dt, A, B, C, D)
+    np.testing.assert_allclose(y, want_y, atol=5e-5, rtol=5e-5)
+    np.testing.assert_allclose(h, want_h, atol=5e-5, rtol=5e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("n,d,f,e,tile", [(8, 64, 96, 4, 32), (32, 128, 256, 3, 128)])
+def test_moe_experts(n, d, f, e, tile, dtype):
+    ks = jax.random.split(KEY, 5)
+    x = jax.random.normal(ks[0], (n, d), jnp.float32).astype(dtype)
+    comb = jax.random.uniform(ks[1], (n, e)) * (jax.random.uniform(
+        ks[1], (n, e)) > 0.5)
+    wg, wu = (jax.random.normal(k, (e, d, f), jnp.float32) * d ** -0.5
+              for k in ks[2:4])
+    wd = jax.random.normal(ks[4], (e, f, d), jnp.float32) * f ** -0.5
+    wg, wu, wd = (w.astype(dtype) for w in (wg, wu, wd))
+    out = ops.moe_experts(x, comb, wg, wu, wd, tile=tile, interpret=True)
+    np.testing.assert_allclose(out, ref.ref_moe_experts(x, comb, wg, wu, wd),
+                               **tol(dtype))

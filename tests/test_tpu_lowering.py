@@ -182,3 +182,31 @@ def test_decode_step_updates_the_stacked_cache_in_place(one_chip, per_row):
                            {period, (1,) + period, (periods,) + period})
     assert [m for m in movers if m[0] != "dynamic-slice"] == []
     assert len(movers) <= 2, movers
+
+
+def test_moe_experts_lowers_at_jamba_widths(one_chip):
+    """The decode expert kernel at Jamba's widths (32 rows, 8 held experts
+    of 4096 x 14336): it lowers, keeps its name for the trace, and needs no
+    temporary (its weights are read where they lie)."""
+    from repro.kernels.moe_experts import moe_experts
+
+    n, d, f, e = 32, 4096, 14336, 8
+    shapes = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in (
+        ((n, d), jnp.bfloat16), ((n, e), jnp.float32),
+        ((e, d, f), jnp.bfloat16), ((e, d, f), jnp.bfloat16),
+        ((e, f, d), jnp.bfloat16))]
+    compiled = jax.jit(functools.partial(moe_experts, interpret=False)).lower(
+        *shapes).compile()
+    assert re.search(r"%moe_experts\.\d+ = .*custom-call", compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+def test_mamba_scan_lowers_returning_its_state(one_chip):
+    from repro.kernels.mamba_scan import mamba_scan
+
+    b, s, dm, n = 1, 256, 256, 16
+    args = [jnp.zeros(sh, jnp.float32) for sh in (
+        (b, s, dm), (b, s, dm), (dm, n), (b, s, n), (b, s, n), (dm,))]
+    fn = functools.partial(mamba_scan, chunk=128, interpret=False,
+                           return_state=True)
+    assert "tpu_custom_call" in _compile_for_chip(fn, args, one_chip)
