@@ -116,10 +116,11 @@ def _annotate_cache(c, cfg: ModelConfig, rt: Runtime, lead: tuple = ()):
     return annotate(c, *lead, "batch", "kv_seq", None, None)
 
 
-def _attn_decode(p: Params, x, pos, cfg: ModelConfig, positions, write):
+def _attn_decode(p: Params, x, pos, cfg: ModelConfig, positions, write,
+                 attend):
     """The one-token attention step both cache forms share: norm, q/k/v,
-    RoPE, then ``write(k, v, pos_arr) -> (ck, cv, cache)`` stores the
-    token's K/V and returns the [B,Smax,KH,hd] views to attend over."""
+    RoPE, then ``write(k, v, pos_arr) -> cache`` stores the token's K/V and
+    ``attend(q, cache, kv_len)`` attends over it."""
     with jax.named_scope("attn"):
         b = x.shape[0]
         h = (common.rmsnorm(x, p["norm"].value, cfg.norm_eps)
@@ -130,8 +131,8 @@ def _attn_decode(p: Params, x, pos, cfg: ModelConfig, positions, write):
             positions = (jnp.full((b, 1), pos_arr, jnp.int32)
                          if pos_arr.ndim == 0 else pos_arr[:, None])
         q, k = _rope(cfg, q, k, positions)
-        ck, cv, cache = write(k, v, pos_arr)
-        out = common.decode_attention(q[:, 0], ck, cv, kv_len=pos + 1)
+        cache = write(k, v, pos_arr)
+        out = attend(q[:, 0], cache, pos_arr + 1)
         y = jnp.einsum("bhk,hkd->bd", out,
                        p["wo"].value.astype(cfg.cdtype))[:, None]
         return x + y, cache
@@ -161,10 +162,13 @@ def attn_decode(p: Params, x, cache: Params, pos, cfg: ModelConfig, rt: Runtime,
                 k[:, 0].astype(cache["k"].dtype))
             cv = cache["v"].at[rows, pos_arr].set(
                 v[:, 0].astype(cache["v"].dtype))
-        ck, cv = _annotate_cache(ck, cfg, rt), _annotate_cache(cv, cfg, rt)
-        return ck, cv, {"k": ck, "v": cv}
+        return {"k": _annotate_cache(ck, cfg, rt),
+                "v": _annotate_cache(cv, cfg, rt)}
 
-    return _attn_decode(p, x, pos, cfg, positions, write)
+    def attend(q, c, kv_len):
+        return common.decode_attention(q, c["k"], c["v"], kv_len)
+
+    return _attn_decode(p, x, pos, cfg, positions, write, attend)
 
 
 def attn_decode_stacked(p: Params, x, cache: Params, period, pos,
@@ -172,7 +176,8 @@ def attn_decode_stacked(p: Params, x, cache: Params, period, pos,
     """:func:`attn_decode` against the whole stacked cache, k/v
     [P,B,Smax,KH,hd], which rides in the layer scan's carry: the token's
     K/V is scattered in place at ``(period, row, pos)`` and attention reads
-    ``cache[period]`` through a dynamic index, so nothing is written back
+    period ``period`` of the stack itself, each row up to its ``kv_len``
+    (:func:`common.decode_attention_stacked`), so nothing is written back
     and the stack is never copied.
     """
     def write(k, v, pos_arr):
@@ -184,12 +189,13 @@ def attn_decode_stacked(p: Params, x, cache: Params, period, pos,
         def put(c, new):
             c = c.at[at].set(new[:, 0].astype(c.dtype))
             return _annotate_cache(c, cfg, rt, lead=(None,))
-        ck, cv = put(cache["k"], k), put(cache["v"], v)
-        return (lax.dynamic_index_in_dim(ck, period, keepdims=False),
-                lax.dynamic_index_in_dim(cv, period, keepdims=False),
-                {"k": ck, "v": cv})
+        return {"k": put(cache["k"], k), "v": put(cache["v"], v)}
 
-    return _attn_decode(p, x, pos, cfg, positions, write)
+    def attend(q, c, kv_len):
+        return common.decode_attention_stacked(
+            q, c["k"], c["v"], period, jnp.broadcast_to(kv_len, q.shape[:1]))
+
+    return _attn_decode(p, x, pos, cfg, positions, write, attend)
 
 
 def attn_cross_decode(p: Params, x, mem_kv, cfg: ModelConfig):

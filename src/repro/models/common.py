@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro.parallel import sharding
 from repro.parallel.sharding import Param, annotate
 
 NEG_INF = -1e30
@@ -188,6 +189,30 @@ def decode_attention(q, k, v, kv_len) -> jax.Array:
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bkgs,bskd->bkgd", probs, v.astype(jnp.float32))
     return out.reshape(b, h, d).astype(q.dtype)
+
+
+def decode_attention_stacked(q, k, v, period, kv_len) -> jax.Array:
+    """:func:`decode_attention` over period ``period`` of a stacked cache.
+    q: [B,H,D]; k,v: [P,B,S,KH,D]; kv_len: [B].
+
+    Lowered for a TPU with no sharding mesh active, the
+    ``flash_decode_stacked`` kernel reads the period where it lies, up to
+    each row's ``kv_len``. Elsewhere XLA slices the period out: on a CPU,
+    and for a sharded cache, which the kernel does not partition.
+    """
+    def sliced(q, k, v, period, kv_len):
+        return decode_attention(q, lax.dynamic_index_in_dim(k, period, keepdims=False),
+                                lax.dynamic_index_in_dim(v, period, keepdims=False),
+                                kv_len)
+
+    period = jnp.asarray(period, jnp.int32)
+    if sharding.current() is not None:
+        return sliced(q, k, v, period, kv_len)
+    from repro.kernels.flash_decode import flash_decode_stacked
+    return lax.platform_dependent(
+        q, k, v, period, kv_len,
+        tpu=functools.partial(flash_decode_stacked, interpret=False),
+        default=sliced)
 
 
 def attention(q, k, v, *, causal: bool = True, impl: str = "auto",

@@ -292,7 +292,13 @@ class SlotPool:
         # the slot bookkeeping after the read-back is the step's self time
         with tracing.span("repro.pool.step", active=active):
             with tracing.span("repro.pool.step_inputs"):
-                pos = jnp.asarray([s.pos for s in self._slots], jnp.int32)
+                positions = [s.pos for s in self._slots]
+                # each row's kv_len (pos + 1): the positions decode attention
+                # reads; it fetches whole blocks, so this undercounts its
+                # reads by less than a block a row
+                tracing.count("pool.kv_rows_live",
+                              sum(positions) + self.n_slots)
+                pos = jnp.asarray(positions, jnp.int32)
                 tok = jnp.asarray(self._tok)
             with tracing.span("repro.pool.decode"):
                 logits, self.cache = eng._decode(eng.params, self.cache, tok,

@@ -134,3 +134,95 @@ def test_moe_experts(n, d, f, e, tile, dtype):
     out = ops.moe_experts(x, comb, wg, wu, wd, tile=tile, interpret=True)
     np.testing.assert_allclose(out, ref.ref_moe_experts(x, comb, wg, wu, wd),
                                **tol(dtype))
+
+
+@pytest.mark.parametrize("periods,period,kh,h,lens,block_k", [
+    (2, 1, 4, 32, (1, 16, 17, 64), 16),     # yi's G = 8: one, a block edge, max_len
+    (1, 0, 8, 32, (64, 1, 33, 16), 16),     # jamba's G = 4, one period
+    (3, 0, 4, 32, (1, 64, 31, 32), 32),
+    (3, 2, 4, 32, (40,) * 4, 16),           # a scalar pos, broadcast to every row
+], ids=["yi_period1", "jamba_period0", "yi_period0", "scalar_pos"])
+def test_flash_decode_stacked(periods, period, kh, h, lens, block_k):
+    """The stacked-cache kernel over period ``period`` equals the XLA decode
+    attention over ``cache[period]``, each row up to its ``kv_len``."""
+    from repro.models import common
+
+    b, s, d = len(lens), 64, 32
+    ks = jax.random.split(KEY, 3)
+    q = jax.random.normal(ks[0], (b, h, d), jnp.float32).astype(jnp.bfloat16)
+    k = jax.random.normal(ks[1], (periods, b, s, kh, d),
+                          jnp.float32).astype(jnp.bfloat16)
+    v = jax.random.normal(ks[2], (periods, b, s, kh, d),
+                          jnp.float32).astype(jnp.bfloat16)
+    kv_len = jnp.asarray(lens, jnp.int32)
+    out = ops.flash_decode_stacked(q, k, v, jnp.int32(period), kv_len,
+                                   block_k=block_k, interpret=True)
+    want = common.decode_attention(q, k[period], v[period], kv_len)
+    np.testing.assert_allclose(out.astype(jnp.float32),
+                               want.astype(jnp.float32),
+                               **tol(jnp.bfloat16))
+
+
+def _attention_rounded_like_the_kernel(q, k, v, period, kv_len):
+    """``common.decode_attention`` over ``cache[period]`` with the stacked
+    kernel's rounding in one block: q.K of the stored bfloat16 values in
+    float32, scaled after the dot, and P rounded to bfloat16 before P.V."""
+    k = jax.lax.dynamic_index_in_dim(k, period, keepdims=False)
+    v = jax.lax.dynamic_index_in_dim(v, period, keepdims=False)
+    b, h, d = q.shape
+    s, kh = k.shape[1], k.shape[2]
+    scores = jnp.einsum("bkgd,bskd->bkgs",
+                        q.reshape(b, kh, h // kh, d).astype(jnp.float32),
+                        k.astype(jnp.float32)) * d ** -0.5
+    live = jnp.arange(s)[None, :] < kv_len[:, None]
+    scores = jnp.where(live[:, None, None], scores, -1e30)
+    p = jnp.exp(scores - jnp.max(scores, axis=-1, keepdims=True))
+    out = jnp.einsum("bkgs,bskd->bkgd",
+                     p.astype(v.dtype).astype(jnp.float32),
+                     v.astype(jnp.float32)) / jnp.sum(p, -1, keepdims=True)
+    return out.reshape(b, h, d).astype(q.dtype)
+
+
+@pytest.mark.parametrize("per_row", [True, False], ids=["per_row", "scalar"])
+def test_decode_step_through_the_stacked_kernel(monkeypatch, per_row):
+    """``transformer.decode_step`` with its attention sent through the
+    stacked-cache kernel (one block a row, so that its rounding can be
+    reproduced) gives the logits and cache of the XLA step whose attention
+    rounds as the kernel does: a slip in the kernel's period, row or
+    ``kv_len`` indexing moves the logits by far more than the tolerance.
+    The dispatch to the kernel on a TPU is checked where the step compiles
+    for a described v5e (``tests/test_tpu_lowering.py``); the kernel's
+    block clamping at block edges, by ``test_flash_decode_stacked``."""
+    from repro.kernels.flash_decode import flash_decode_stacked
+    from repro.models import common, transformer
+    from repro.models.config import ModelConfig, Runtime
+
+    cfg = ModelConfig(name="tiny", family="dense", n_layers=3, d_model=64,
+                      n_heads=8, n_kv_heads=2, head_dim=16, d_ff=128,
+                      vocab_size=128, param_dtype="bfloat16",
+                      compute_dtype="bfloat16", tie_embeddings=False)
+    slots, max_len = 4, 64
+    params = transformer.init_lm(KEY, cfg)
+    cache = jax.tree_util.tree_map(
+        lambda a: jax.random.normal(jax.random.fold_in(KEY, a.ndim), a.shape,
+                                    jnp.float32).astype(a.dtype),
+        transformer.init_cache(cfg, slots, max_len, cfg.cdtype))
+    tokens = jnp.arange(slots, dtype=jnp.int32)[:, None]
+    pos = jnp.asarray([0, 15, 16, 63], jnp.int32) if per_row else jnp.int32(31)
+
+    def step(attention):
+        monkeypatch.setattr(common, "decode_attention_stacked", attention)
+        return jax.jit(lambda p, c, t, q: transformer.decode_step(
+            p, c, t, q, cfg, Runtime()))(params, cache, tokens, pos)
+
+    want_logits, want_cache = step(_attention_rounded_like_the_kernel)
+    logits, new_cache = step(
+        lambda q, k, v, period, kv_len: flash_decode_stacked(
+            q, k, v, period, kv_len, block_k=max_len, interpret=True))
+    scale = float(jnp.max(jnp.abs(want_logits)))
+    np.testing.assert_allclose(logits, want_logits, rtol=0, atol=1e-3 * scale)
+    for got, want in zip(jax.tree_util.tree_leaves(new_cache),
+                         jax.tree_util.tree_leaves(want_cache)):
+        np.testing.assert_allclose(got.astype(jnp.float32),
+                                   want.astype(jnp.float32),
+                                   rtol=1e-2, atol=1e-2)

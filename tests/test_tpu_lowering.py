@@ -142,19 +142,16 @@ def _cache_movers(hlo: str, shapes: set) -> list:
     return found
 
 
-@pytest.mark.parametrize("per_row", [True, False], ids=["per_row", "scalar"])
-def test_decode_step_updates_the_stacked_cache_in_place(one_chip, per_row):
-    """The donated decode step writes each token into the stacked KV cache
-    in place, for per-row and for scalar positions: no period's K/V is
-    written back, the stack is not copied, and the only period-sized slices
-    are attention's reads (one per cache leaf), so the step's temporaries
-    stay below one period's K."""
+def _compile_decode_step(one_chip, periods, kv_heads, per_row, rt):
+    """The donated decode step of a bfloat16 decoder of ``periods`` layers
+    (32 heads of 128 over ``kv_heads``), 32 slots of 4,096 positions,
+    compiled for the described chip; returns it and one period's K shape."""
     from repro.models import transformer
-    from repro.models.config import ModelConfig, Runtime
+    from repro.models.config import ModelConfig
 
-    periods, slots, max_len, kv_heads, hd = 4, 8, 2048, 4, 128
+    slots, max_len, hd = 32, 4096, 128
     cfg = ModelConfig(name="guard", family="dense", n_layers=periods,
-                      d_model=1024, n_heads=8, n_kv_heads=kv_heads,
+                      d_model=4096, n_heads=32, n_kv_heads=kv_heads,
                       head_dim=hd, d_ff=2048, vocab_size=1024,
                       param_dtype="bfloat16", compute_dtype="bfloat16",
                       tie_embeddings=False)
@@ -172,16 +169,58 @@ def test_decode_step_updates_the_stacked_cache_in_place(one_chip, per_row):
     pos = jax.ShapeDtypeStruct((slots,) if per_row else (), jnp.int32,
                                sharding=one_chip)
     step = jax.jit(lambda p, c, t, q: transformer.decode_step(
-        p, c, t, q, cfg, Runtime()), donate_argnums=(1,))
-    compiled = step.lower(params, cache, tokens, pos).compile()
+        p, c, t, q, cfg, rt), donate_argnums=(1,))
+    return (step.lower(params, cache, tokens, pos).compile(),
+            (slots, max_len, kv_heads, hd))
 
-    period = (slots, max_len, kv_heads, hd)
-    period_bytes = slots * max_len * kv_heads * hd * 2
+
+def _reads_the_stacked_cache_in_place(compiled, periods, period):
+    """No period of the cache is written back, copied or sliced out, the
+    step's temporaries stay below one period's K, and attention is the
+    stacked-cache kernel, once a layer."""
+    text = compiled.as_text()
+    period_bytes = 2 * period[0] * period[1] * period[2] * period[3]
     assert compiled.memory_analysis().temp_size_in_bytes < period_bytes
-    movers = _cache_movers(compiled.as_text(),
-                           {period, (1,) + period, (periods,) + period})
-    assert [m for m in movers if m[0] != "dynamic-slice"] == []
-    assert len(movers) <= 2, movers
+    movers = _cache_movers(text, {period, (1,) + period, (periods,) + period})
+    assert movers == []
+    return len(re.findall(r"%flash_decode_stacked[.\d]* = .*custom-call", text))
+
+
+@pytest.mark.parametrize("per_row", [True, False], ids=["per_row", "scalar"])
+def test_decode_step_updates_the_stacked_cache_in_place(one_chip, per_row):
+    """The donated decode step writes each token into the stacked KV cache
+    in place and its attention reads each period where it lies, for
+    per-row and for scalar positions: no period's K/V is written back,
+    copied or sliced out, and the layer scan calls the stacked-cache
+    kernel."""
+    from repro.models.config import Runtime
+
+    periods = 4
+    compiled, period = _compile_decode_step(one_chip, periods, 4, per_row,
+                                            Runtime())
+    assert _reads_the_stacked_cache_in_place(compiled, periods, period) == 1
+
+
+def test_decode_step_unrolled_at_jamba_widths(one_chip):
+    """Periods unrolled (``scan_layers`` off, as the hybrid cell runs) at
+    Jamba's attention widths, 8 KV heads of 32 query heads: each period's
+    attention is the kernel, given its period as a constant."""
+    from repro.models.config import Runtime
+
+    periods = 2
+    compiled, period = _compile_decode_step(one_chip, periods, 8, True,
+                                            Runtime(scan_layers=False))
+    assert _reads_the_stacked_cache_in_place(compiled, periods,
+                                             period) == periods
+
+
+def test_flash_decode_probe_is_not_the_serving_kernel(one_chip):
+    """The characterization's ``flash_decode`` probe lowers its own kernel,
+    not the serving step's stacked-cache one."""
+    fn, args = build_fused("flash_decode", FUSED_LENS.chip[0], interpret=False)
+    text = _compile_for_chip(fn, args, one_chip)
+    assert "tpu_custom_call" in text
+    assert "flash_decode_stacked" not in text
 
 
 def test_moe_experts_lowers_at_jamba_widths(one_chip):

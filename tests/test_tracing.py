@@ -134,6 +134,27 @@ def test_pool_and_scheduler_spans_carry_uid(engine, traced):
     assert not _named(spans, "repro.pool.sample")     # greedy pool
 
 
+def test_pool_step_counts_the_live_cache_rows(engine, traced):
+    pool = engine.slots(3)
+    pool.admit(0, [1, 2, 3], uid=1, max_new=4)
+    pool.admit(2, [4, 5], uid=2, max_new=4)
+    pool.step()
+    pool.step()
+    _, counts = tracing.collect()
+    live = [c.n for c in counts if c.name == "pool.kv_rows_live"]
+    # rows at 3, 0 (free) and 2, then 4, 0 and 3: each reads pos + 1
+    assert live == [4 + 1 + 3, 5 + 1 + 4]
+
+
+def test_pool_step_counts_nothing_with_tracing_off(engine):
+    tracing.disable()
+    tracing.collect()
+    pool = engine.slots(2)
+    pool.admit(0, [1, 2], uid=1, max_new=2)
+    pool.step()
+    assert tracing.collect() == ([], [])
+
+
 def test_pool_sample_span_on_the_temperature_path(engine, traced):
     pool = engine.slots(2)
     pool.temperature = 0.7
