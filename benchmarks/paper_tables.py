@@ -11,11 +11,9 @@ Paper artifact -> bench:
   (Section I purpose) serving predicted-vs-meas -> bench_serving_cost
   (framework) attention/kernel-path comparison  -> bench_attention_impls
   (framework) sharded vs serial fan-out scaling -> bench_fanout_scaling
-  (deliverable g) roofline table from dry-runs  -> bench_roofline
 """
 from __future__ import annotations
 
-import glob
 import os
 import time
 
@@ -27,7 +25,7 @@ from repro.api import Plan, Session
 from repro.core import chains, membench, optlevels, perfmodel
 from repro.core.optlevels import OPT_LEVELS
 from repro.core.timing import Timer
-from repro.utils import dump_json, load_json, markdown_table
+from repro.utils import dump_json
 
 RESULTS = os.path.join(os.path.dirname(__file__), "results")
 
@@ -299,25 +297,3 @@ def bench_attention_impls(timer: Timer) -> list[tuple[str, float, str]]:
                      f"B{b} S{s} H{h} D{d} f32 (host CPU)"))
     return rows
 
-
-# ------------------------------------------------------- deliverable g table
-def bench_roofline(_: Timer) -> list[tuple[str, float, str]]:
-    files = sorted(glob.glob(f"{RESULTS}/dryrun/*__16x16.json"))
-    rows_out, md_rows = [], []
-    for f in files:
-        rec = load_json(f)
-        if rec.get("status") != "ok":
-            continue
-        r = rec["roofline"]
-        t_dom = max(r["t_compute"], r["t_memory"], r["t_collective"])
-        md_rows.append([r[k] for k in ("arch", "shape")] +
-                       [f"{r['t_compute']*1e3:.2f}", f"{r['t_memory']*1e3:.2f}",
-                        f"{r['t_collective']*1e3:.2f}", r["dominant"],
-                        f"{r['useful_ratio']:.1%}", f"{r['roofline_fraction']:.2%}"])
-        rows_out.append((f"roofline.{r['arch']}.{r['shape']}", t_dom * 1e6,
-                         f"{r['dominant']}-bound roofline={r['roofline_fraction']:.2%}"))
-    md = markdown_table(["arch", "shape", "T_comp(ms)", "T_mem(ms)",
-                         "T_coll(ms)", "bound", "useful", "roofline"], md_rows)
-    with open(f"{RESULTS}/roofline_table.md", "w") as f:
-        f.write(md)
-    return rows_out

@@ -1,4 +1,5 @@
-"""Generate EXPERIMENTS.md §Dry-run / §Roofline / §Perf tables from results.
+"""Print the serving SLO table (predicted against measured percentiles)
+from the samples ``bench_serving_slo`` saved under ``benchmarks/results/``.
 
   PYTHONPATH=src:. python benchmarks/report.py > /tmp/report.md
 """
@@ -8,20 +9,9 @@ import glob
 import json
 import os
 
-from repro.utils import human_bytes, markdown_table, percentiles
+from repro.utils import markdown_table, percentiles
 
 RES = os.path.join(os.path.dirname(__file__), "results")
-
-_RECO = {
-    "compute": "compute-bound: raise MXU utilization (larger per-device tiles, "
-               "fewer pad/transposes) or accept — this is the roofline target.",
-    "memory": "memory-bound: cut HBM round-trips — bf16 attention probs, "
-              "Pallas flash kernel keeps the prob tile in VMEM, larger fused "
-              "blocks, fewer remat recomputes.",
-    "collective": "collective-bound: reshard to kill the dominant gather "
-                  "(inference sharding for decode, expert-combine reshard, "
-                  "overlap via collective-matmul/async flags).",
-}
 
 
 def _load(pattern):
@@ -32,67 +22,6 @@ def _load(pattern):
         except Exception:  # noqa: BLE001
             pass
     return out
-
-
-def dryrun_table() -> str:
-    rows = []
-    for rec in _load(f"{RES}/dryrun/*.json"):
-        if rec.get("status") == "skip":
-            rows.append([rec["arch"], rec["shape"], rec["mesh"], "SKIP",
-                         "-", "-", "-", rec["reason"][:60]])
-            continue
-        if rec.get("status") != "ok":
-            rows.append([rec.get("arch"), rec.get("shape"), rec.get("mesh"),
-                         "FAIL", "-", "-", "-", rec.get("error", "")[:60]])
-            continue
-        ma = rec["memory_analysis"]
-        r = rec["roofline"]
-        coll = ", ".join(f"{k}x{int(v['count'])}({human_bytes(v['wire_bytes'])})"
-                         for k, v in sorted(r["collectives"].items()))
-        rows.append([rec["arch"], rec["shape"], rec["mesh"], "ok",
-                     human_bytes(ma["argument_size_in_bytes"]),
-                     human_bytes(ma["temp_size_in_bytes"]),
-                     f"{rec['compile_s']:.0f}s", coll[:90] or "none"])
-    return markdown_table(
-        ["arch", "shape", "mesh", "status", "args/dev", "temp/dev",
-         "compile", "collective schedule (wire bytes/dev/step)"], rows)
-
-
-def roofline_table() -> str:
-    rows = []
-    for rec in _load(f"{RES}/dryrun/*__16x16.json"):
-        if rec.get("status") != "ok":
-            if rec.get("status") == "skip":
-                rows.append([rec["arch"], rec["shape"], "—", "—", "—", "—",
-                             "—", "—", "—", "skipped: " + rec["reason"][:48]])
-            continue
-        r = rec["roofline"]
-        rows.append([
-            r["arch"], r["shape"],
-            f"{r['t_compute']*1e3:.2f}", f"{r['t_memory']*1e3:.2f}",
-            f"{r['t_collective']*1e3:.2f}", r["dominant"],
-            f"{r['model_flops']:.2e}", f"{r['useful_ratio']:.1%}",
-            f"{r['roofline_fraction']:.2%}", _RECO[r["dominant"]][:80]])
-    return markdown_table(
-        ["arch", "shape", "T_comp(ms)", "T_mem(ms)", "T_coll(ms)", "bound",
-         "MODEL_FLOPS", "useful", "roofline", "to move the dominant term"],
-        rows)
-
-
-def perf_table() -> str:
-    rows = []
-    for rec in _load(f"{RES}/perf/*.json"):
-        if rec.get("status") != "ok":
-            continue
-        r = rec["roofline"]
-        rows.append([f"{rec['arch']}/{rec['shape']}", rec["tag"],
-                     json.dumps(rec.get("overrides", {}))[:60],
-                     f"{r['t_compute']*1e3:.2f}", f"{r['t_memory']*1e3:.2f}",
-                     f"{r['t_collective']*1e3:.2f}", r["dominant"],
-                     f"{r['roofline_fraction']:.2%}"])
-    return markdown_table(
-        ["cell", "variant", "knobs", "T_comp(ms)", "T_mem(ms)", "T_coll(ms)",
-         "bound", "roofline"], rows)
 
 
 def serving_slo_table() -> str:
@@ -125,11 +54,5 @@ def serving_slo_table() -> str:
 
 
 if __name__ == "__main__":
-    print("## Dry-run table\n")
-    print(dryrun_table())
-    print("\n## Roofline table (single-pod 16x16)\n")
-    print(roofline_table())
-    print("\n## Perf iterations\n")
-    print(perf_table())
-    print("\n## Serving SLO (predicted vs measured)\n")
+    print("## Serving SLO (predicted vs measured)\n")
     print(serving_slo_table())

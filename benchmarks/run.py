@@ -41,7 +41,6 @@ def main() -> None:
             t, quick=args.quick),
         "fanout": lambda t: pt.bench_fanout_scaling(t, quick=args.quick),
         "attention": lambda t: pt.bench_attention_impls(t),
-        "roofline": lambda t: pt.bench_roofline(t),
     }
     only = set(args.only.split(",")) if args.only else set(benches)
     print("name,us_per_call,derived")

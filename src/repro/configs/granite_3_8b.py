@@ -2,7 +2,7 @@
 [hf:ibm-granite/granite-3.0-8b-base]. Note v49155 is not divisible by the
 16-way model axis -> vocab replicates (sharding rules fall back); embedding
 memory is FSDP-sharded over data instead."""
-from repro.configs.registry import ArchSpec, FULL_ATTENTION_SKIP, register
+from repro.configs.registry import ArchSpec, register
 from repro.models.config import ModelConfig
 
 
@@ -17,4 +17,4 @@ def spec() -> ArchSpec:
         name="granite-smoke", family="dense",
         n_layers=2, d_model=64, n_heads=4, n_kv_heads=2, d_ff=160,
         vocab_size=515, tie_embeddings=True)
-    return ArchSpec(cfg, smoke, skips=dict([FULL_ATTENTION_SKIP]))
+    return ArchSpec(cfg, smoke)

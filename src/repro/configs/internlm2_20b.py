@@ -1,6 +1,6 @@
 """InternLM2-20B: 48L d6144 48H(kv8) ff16384 v92544, dense GQA
 [arXiv:2403.17297; hf]. Head-parallel TP (48/16=3, kv duplicated 2x)."""
-from repro.configs.registry import ArchSpec, FULL_ATTENTION_SKIP, register
+from repro.configs.registry import ArchSpec, register
 from repro.models.config import ModelConfig
 
 
@@ -15,4 +15,4 @@ def spec() -> ArchSpec:
         name="internlm2-smoke", family="dense",
         n_layers=3, d_model=96, n_heads=6, n_kv_heads=2, d_ff=256,
         vocab_size=512, tie_embeddings=False)
-    return ArchSpec(cfg, smoke, skips=dict([FULL_ATTENTION_SKIP]))
+    return ArchSpec(cfg, smoke)

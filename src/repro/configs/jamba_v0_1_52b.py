@@ -5,8 +5,7 @@ same shape as Jamba 1.5/2 Mini. In each period of 8, layer 4 is attention
 (``expert_layer_offset`` 1, period 2). Attention takes no positional
 encoding; the Mamba mixer RMS-norms dt, B and C after ``x_proj``; the
 router's top-2 weights are the softmax over 16, not renormalized; MoE is
-dropless. Sub-quadratic -> runs long_500k (SSM state O(1); the 4 attention
-layers use a sequence-sharded KV cache with flash-decode LSE combine)."""
+dropless."""
 from repro.configs.registry import ArchSpec, register
 from repro.models.config import ModelConfig
 
@@ -29,4 +28,4 @@ def spec() -> ArchSpec:
         name="jamba-smoke", family="hybrid",
         n_layers=8, d_model=64, n_heads=4, n_kv_heads=2, d_ff=96,
         vocab_size=512, n_experts=4, ssm_state=8, **_JAMBA)
-    return ArchSpec(cfg, smoke, skips={})
+    return ArchSpec(cfg, smoke)

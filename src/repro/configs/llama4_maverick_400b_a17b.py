@@ -2,7 +2,7 @@
 top-1 interleaved every other layer + shared expert, early-fusion backbone
 [hf:meta-llama/Llama-4 family; unverified]. 40 q-heads do not divide the
 16-way model axis -> context-parallel attention (DESIGN.md section 5)."""
-from repro.configs.registry import ArchSpec, FULL_ATTENTION_SKIP, register
+from repro.configs.registry import ArchSpec, register
 from repro.models.config import ModelConfig
 
 
@@ -21,4 +21,4 @@ def spec() -> ArchSpec:
         vocab_size=512, period=(("attn", "moe"), ("attn", "dense")),
         n_experts=8, top_k=1, shared_expert=True, tie_embeddings=False,
         attn_parallelism="context")
-    return ArchSpec(cfg, smoke, skips=dict([FULL_ATTENTION_SKIP]))
+    return ArchSpec(cfg, smoke)

@@ -1,7 +1,7 @@
 """Llama-4 Scout 17B-A16E: 48L d5120 40H(kv8) ff8192 v202048, MoE 16e top-1
 every layer + shared expert [hf:meta-llama/Llama-4-Scout-17B-16E; unverified].
 Context-parallel attention (40 heads vs 16-way TP)."""
-from repro.configs.registry import ArchSpec, FULL_ATTENTION_SKIP, register
+from repro.configs.registry import ArchSpec, register
 from repro.models.config import ModelConfig
 
 
@@ -19,4 +19,4 @@ def spec() -> ArchSpec:
         n_layers=2, d_model=64, n_heads=10, n_kv_heads=2, d_ff=96,
         vocab_size=512, period=(("attn", "moe"),), n_experts=4, top_k=1,
         shared_expert=True, tie_embeddings=False, attn_parallelism="context")
-    return ArchSpec(cfg, smoke, skips=dict([FULL_ATTENTION_SKIP]))
+    return ArchSpec(cfg, smoke)

@@ -2,8 +2,7 @@
 
 The paper's Table I describes the seven GPUs it characterizes. The analog
 here is the table of execution targets the suite runs against — the host CPU
-backend (measured in this container) and the TPU v5e production target
-(datasheet constants mandated for §Roofline). ``suite()`` bundles what the
+backend and the TPU v5e (datasheet peaks). ``suite()`` bundles what the
 paper's tool sweeps: the op registry, opt levels, and memory working sets.
 """
 from __future__ import annotations

@@ -2,7 +2,7 @@
 (t/h/w sections 16/24/24), dynamic-resolution ViT frontend STUBBED: cells
 feed precomputed patch embeddings + 3D positions [arXiv:2409.12191; hf].
 12 heads vs 16-way TP -> context-parallel attention."""
-from repro.configs.registry import ArchSpec, FULL_ATTENTION_SKIP, register
+from repro.configs.registry import ArchSpec, register
 from repro.models.config import ModelConfig
 
 
@@ -19,4 +19,4 @@ def spec() -> ArchSpec:
         n_layers=2, d_model=96, n_heads=6, n_kv_heads=2, d_ff=256,
         vocab_size=512, mrope_sections=(2, 3, 3), tie_embeddings=True,
         input_kind="patch_embeddings")
-    return ArchSpec(cfg, smoke, skips=dict([FULL_ATTENTION_SKIP]))
+    return ArchSpec(cfg, smoke)

@@ -1,9 +1,7 @@
 """Architecture registry: --arch <id> resolves here.
 
-Every assigned architecture registers its exact ``ModelConfig``, a reduced
-``smoke`` config of the same family, and its applicable input-shape cells
-(the mandated 4: train_4k / prefill_32k / decode_32k / long_500k; long_500k
-only for sub-quadratic archs, per the assignment rule — skips are recorded).
+Every architecture registers its exact ``ModelConfig`` and a reduced
+``smoke`` config of the same family.
 """
 from __future__ import annotations
 
@@ -26,23 +24,11 @@ ARCH_IDS = (
     "seamless-m4t-large-v2",
 )
 
-# shape id -> (seq_len, global_batch, step kind)
-SHAPES: dict[str, tuple[int, int, str]] = {
-    "train_4k": (4096, 256, "train"),
-    "prefill_32k": (32768, 32, "prefill"),
-    "decode_32k": (32768, 128, "decode"),
-    "long_500k": (524288, 1, "decode"),
-}
-
 
 @dataclasses.dataclass(frozen=True)
 class ArchSpec:
     config: ModelConfig
     smoke: ModelConfig
-    skips: dict[str, str]        # shape id -> reason
-
-    def applicable_shapes(self) -> list[str]:
-        return [s for s in SHAPES if s not in self.skips]
 
 
 _REGISTRY: dict[str, Callable[[], ArchSpec]] = {}
@@ -65,7 +51,3 @@ def get(arch_id: str) -> ArchSpec:
 def all_arch_ids() -> tuple[str, ...]:
     return ARCH_IDS
 
-
-FULL_ATTENTION_SKIP = ("long_500k",
-                       "full quadratic attention at 524k seq: skipped per "
-                       "assignment rule (sub-quadratic archs only)")

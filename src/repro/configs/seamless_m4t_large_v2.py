@@ -2,7 +2,7 @@
 ff8192 v256206, enc-dec [arXiv:2308.11596; hf]. Speech frontend STUBBED:
 cells feed precomputed frame embeddings (enc len = seq/4). Decoder has a KV
 cache -> decode shapes run."""
-from repro.configs.registry import ArchSpec, FULL_ATTENTION_SKIP, register
+from repro.configs.registry import ArchSpec, register
 from repro.models.config import ModelConfig
 
 
@@ -18,4 +18,4 @@ def spec() -> ArchSpec:
         n_layers=2, d_model=64, n_heads=4, n_kv_heads=4, d_ff=128,
         vocab_size=512, n_encoder_layers=2, tie_embeddings=True,
         input_kind="frame_embeddings")
-    return ArchSpec(cfg, smoke, skips=dict([FULL_ATTENTION_SKIP]))
+    return ArchSpec(cfg, smoke)

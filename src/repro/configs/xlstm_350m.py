@@ -1,5 +1,5 @@
 """xLSTM-350M: 24L d1024 4H(kv4) no-FFN v50304, sLSTM+mLSTM [7:1]
-[arXiv:2405.04517; unverified]. Recurrent state O(1) -> runs long_500k."""
+[arXiv:2405.04517; unverified]."""
 from repro.configs.registry import ArchSpec, register
 from repro.models.config import ModelConfig
 
@@ -17,4 +17,4 @@ def spec() -> ArchSpec:
         name="xlstm-smoke", family="ssm",
         n_layers=8, d_model=64, n_heads=4, n_kv_heads=4, d_ff=0,
         vocab_size=512, period=_PERIOD, ssm_expand=2, tie_embeddings=True)
-    return ArchSpec(cfg, smoke, skips={})
+    return ArchSpec(cfg, smoke)

@@ -1,6 +1,6 @@
 """Yi-9B: 48L d4096 32H(kv4) ff11008 v64000, llama-arch GQA
 [arXiv:2403.04652; hf]. Head-parallel TP (32/16=2, kv duplicated 4x)."""
-from repro.configs.registry import ArchSpec, FULL_ATTENTION_SKIP, register
+from repro.configs.registry import ArchSpec, register
 from repro.models.config import ModelConfig
 
 
@@ -15,4 +15,4 @@ def spec() -> ArchSpec:
         name="yi-smoke", family="dense",
         n_layers=2, d_model=64, n_heads=4, n_kv_heads=1, d_ff=172,
         vocab_size=500, tie_embeddings=False)
-    return ArchSpec(cfg, smoke, skips=dict([FULL_ATTENTION_SKIP]))
+    return ArchSpec(cfg, smoke)

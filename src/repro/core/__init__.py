@@ -10,7 +10,7 @@ machinery the probes wrap:
   - membench.measure_latency(): memory-hierarchy chase (Fig. 6 analog)
   - optlevels: the -O0/-O1/-O3 compiler axis
   - latency_db.LatencyDB: persistent result tables + failures (Table II/III)
-  - perfmodel.Roofline / HloLatencyEstimator: the model-feeding use case
+  - perfmodel.HloLatencyEstimator: the model-feeding use case
   - hlo_analysis: collective traffic + op histograms from HLO text
 
 Deprecated shims (kept for one release): measure.run_suite,
@@ -19,12 +19,12 @@ measure.clock_overhead, membench.sweep — all now route through repro.api.
 from repro.core import chains, hlo_analysis, latency_db, measure, membench, optlevels, perfmodel
 from repro.core.chains import OpSpec, default_registry
 from repro.core.latency_db import LatencyDB, LatencyRecord
-from repro.core.perfmodel import CPU_HOST, TPU_V5E, HardwareSpec, HloLatencyEstimator, Roofline
+from repro.core.perfmodel import CPU_HOST, TPU_V5E, HardwareSpec, HloLatencyEstimator
 from repro.core.timing import Measurement, Timer
 
 __all__ = [
     "chains", "hlo_analysis", "latency_db", "measure", "membench", "optlevels",
     "perfmodel", "OpSpec", "default_registry", "LatencyDB", "LatencyRecord",
-    "Measurement", "Timer", "Roofline", "HloLatencyEstimator", "HardwareSpec",
+    "Measurement", "Timer", "HloLatencyEstimator", "HardwareSpec",
     "TPU_V5E", "CPU_HOST",
 ]

@@ -7,8 +7,8 @@ carries ``backend_config={"known_trip_count":{"n":...}}`` on while ops, so we
 parse the module into its computation call graph and roll costs up with trip
 multipliers — a *corrected* whole-program {FLOPs, bytes, collective-wire
 bytes}. This mirrors how the paper's latency tables are meant to be consumed
-(static instruction counts priced per-op; PPT-GPU-style), and it is what the
-§Roofline terms are computed from.
+(static instruction counts priced per-op; PPT-GPU-style), and it is what
+``perfmodel.HloLatencyEstimator`` prices.
 
 Accounting conventions (matches XLA's):
   * dot FLOPs = 2 x prod(result dims) x prod(contracting dims);
@@ -430,19 +430,6 @@ class ModuleCost:
             in_fusion = name in fused
             for op in comp.ops:
                 yield comp, op, e, in_fusion
-
-    def breakdown(self, top: int = 12) -> dict[str, list]:
-        """Where do the bytes/flops go? Executions-weighted per-op-kind and
-        per-computation ranking — the §Perf iteration's 'profile'."""
-        by_kind_bytes: dict[str, float] = {}
-        by_kind_flops: dict[str, float] = {}
-        for comp, op, e, in_fusion in self._walk_dynamic():
-            c = self._op_cost(comp, op, in_fusion=in_fusion)
-            by_kind_bytes[op.opcode] = by_kind_bytes.get(op.opcode, 0.0) + c.bytes * e
-            by_kind_flops[op.opcode] = by_kind_flops.get(op.opcode, 0.0) + c.flops * e
-        rank_b = sorted(by_kind_bytes.items(), key=lambda kv: -kv[1])[:top]
-        rank_f = sorted(by_kind_flops.items(), key=lambda kv: -kv[1])[:top]
-        return {"bytes_by_opcode": rank_b, "flops_by_opcode": rank_f}
 
     def dynamic_histogram(self) -> dict[tuple[str, int], float]:
         """Dynamic op counts: ``{(opcode, result elements): executions}``.

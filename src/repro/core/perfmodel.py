@@ -1,31 +1,26 @@
 """Analytical performance model fed by the characterization results.
 
 This is the paper's *raison d'être* (Section I: accurate per-instruction
-latencies make performance models like PPT-GPU accurate). Two models:
-
-* :class:`Roofline` — the three-term roofline mandated by the assignment,
-  computed per (arch × shape × mesh) from the compiled dry-run artifact:
-  ``cost_analysis()`` (per-device FLOPs / bytes — verified per-device in
-  probes) plus HLO-parsed collective traffic.
-* :class:`HloLatencyEstimator` — prices a lowered HLO module with *measured*
-  per-op latencies from the LatencyDB: the simulator-feeding use case.
-  Dynamic (trip-count-rolled) instruction counts, a two-term
-  ``max(compute, memory)`` estimate whose memory term comes from the measured
-  pointer-chase ladder, and a :class:`PricedReport` diagnosis with an
-  explicit coverage fraction. :class:`ServingPoint` parses the
-  ``serving.<phase>.<cell>`` rows the ``repro.api.ServingCostProbe`` writes
-  (predicted-vs-measured, docs/serving.md).
+latencies make performance models like PPT-GPU accurate).
+:class:`HloLatencyEstimator` prices a lowered HLO module with *measured*
+per-op latencies from the LatencyDB: the simulator-feeding use case.
+Dynamic (trip-count-rolled) instruction counts, a two-term
+``max(compute, memory)`` estimate whose memory term comes from the measured
+pointer-chase ladder, and a :class:`PricedReport` diagnosis with an
+explicit coverage fraction. :class:`ServingPoint` parses the
+``serving.<phase>.<cell>`` rows the ``repro.api.ServingCostProbe`` writes
+(predicted-vs-measured, docs/serving.md). :class:`HardwareSpec` holds the
+datasheet peaks of the suite's targets (``configs/paper_suite.py``).
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
 import re
-from typing import Any
 
 from repro.core import hlo_analysis
 from repro.core.latency_db import LatencyDB, LatencyRecord
-from repro.utils import human_bytes, human_flops, parse_kv_notes
+from repro.utils import parse_kv_notes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -42,104 +37,12 @@ class HardwareSpec:
         return self.peak_flops / self.hbm_bw
 
 
-# Mandated target constants (assignment §Roofline).
+# Datasheet peaks of one chip.
 TPU_V5E = HardwareSpec(name="tpu-v5e", peak_flops=197e12, hbm_bw=819e9,
                        ici_bw=50e9, hbm_bytes=16 * 2**30, clock_hz=1.7e9)
 # For completeness / cross-checks when running measured benches on this host.
 CPU_HOST = HardwareSpec(name="cpu-host", peak_flops=1e11, hbm_bw=2e10,
                         ici_bw=1e10, hbm_bytes=64 * 2**30, clock_hz=3e9)
-
-
-@dataclasses.dataclass(frozen=True)
-class RooflineReport:
-    arch: str
-    shape: str
-    mesh: str
-    chips: int
-    flops_per_dev: float
-    bytes_per_dev: float
-    collective_wire_bytes_per_dev: float
-    t_compute: float
-    t_memory: float
-    t_collective: float
-    dominant: str
-    model_flops: float           # 6ND (train) / 2ND (decode), active params
-    useful_ratio: float          # model_flops / (flops_per_dev * chips)
-    peak_memory_per_dev: float
-    roofline_fraction: float     # T_dominant==T_compute ? t_comp/t_total : see note
-    collectives: dict[str, dict[str, float]]
-    notes: str = ""
-
-    def bound_summary(self) -> str:
-        return (f"{self.arch}/{self.shape}@{self.mesh}: comp={self.t_compute*1e3:.2f}ms "
-                f"mem={self.t_memory*1e3:.2f}ms coll={self.t_collective*1e3:.2f}ms "
-                f"-> {self.dominant}-bound, useful={self.useful_ratio:.2%}, "
-                f"roofline={self.roofline_fraction:.2%}")
-
-
-def _summary(collectives) -> dict[str, dict[str, float]]:
-    summ: dict[str, dict[str, float]] = {}
-    for c in collectives:
-        d = summ.setdefault(c.kind, {"count": 0, "result_bytes": 0.0, "wire_bytes": 0.0})
-        d["count"] += c.executions
-        d["result_bytes"] += c.result_bytes * c.executions
-        d["wire_bytes"] += c.wire_bytes * c.executions
-    return summ
-
-
-class Roofline:
-    def __init__(self, hw: HardwareSpec = TPU_V5E):
-        self.hw = hw
-
-    def analyze(self, *, arch: str, shape: str, mesh: str, chips: int,
-                cost: dict[str, Any], hlo_text: str, model_flops: float,
-                peak_memory_per_dev: float = 0.0, notes: str = "") -> RooflineReport:
-        # Corrected static costs: cost_analysis() counts while bodies once
-        # (verified), so scan-based programs need the trip-count rollup of
-        # hlo_analysis.ModuleCost. Take max with XLA's own numbers so a parse
-        # miss can only under-correct, never under-report.
-        st = hlo_analysis.static_cost(hlo_text)
-        flops_dev = max(float(cost.get("flops", 0.0)), st.flops)
-        # bytes: prefer the static rollup — XLA's bytes-accessed both
-        # undercounts loops (body x1) and overcounts in-place dynamic-update-
-        # slice; the static conventions are cross-checked in tests. Fall back
-        # to XLA's number when no HLO text is supplied.
-        bytes_dev = st.bytes if st.bytes > 0 else float(cost.get("bytes accessed", 0.0))
-        wire_dev = st.wire_bytes
-        t_comp = flops_dev / self.hw.peak_flops
-        t_mem = bytes_dev / self.hw.hbm_bw
-        t_coll = wire_dev / self.hw.ici_bw
-        terms = {"compute": t_comp, "memory": t_mem, "collective": t_coll}
-        dominant = max(terms, key=terms.get)  # type: ignore[arg-type]
-        total_flops = flops_dev * max(chips, 1)
-        useful = model_flops / total_flops if total_flops else 0.0
-        # Roofline fraction: the fraction of the step's lower-bound time spent
-        # on *useful model math at peak*: (model_flops/chips/peak) / max-term.
-        t_ideal = (model_flops / max(chips, 1)) / self.hw.peak_flops
-        frac = t_ideal / max(max(terms.values()), 1e-30)
-        return RooflineReport(
-            arch=arch, shape=shape, mesh=mesh, chips=chips,
-            flops_per_dev=flops_dev, bytes_per_dev=bytes_dev,
-            collective_wire_bytes_per_dev=wire_dev,
-            t_compute=t_comp, t_memory=t_mem, t_collective=t_coll,
-            dominant=dominant, model_flops=model_flops, useful_ratio=useful,
-            peak_memory_per_dev=peak_memory_per_dev,
-            roofline_fraction=min(frac, 1.0),
-            collectives=_summary(st.collectives), notes=notes)
-
-    @staticmethod
-    def markdown_row(r: RooflineReport) -> list[str]:
-        return [r.arch, r.shape, r.mesh, str(r.chips),
-                human_flops(r.flops_per_dev), human_bytes(r.bytes_per_dev),
-                human_bytes(r.collective_wire_bytes_per_dev),
-                f"{r.t_compute*1e3:.3f}", f"{r.t_memory*1e3:.3f}",
-                f"{r.t_collective*1e3:.3f}", r.dominant,
-                f"{r.useful_ratio:.2%}", f"{r.roofline_fraction:.2%}",
-                human_bytes(r.peak_memory_per_dev)]
-
-    MD_HEADERS = ["arch", "shape", "mesh", "chips", "flops/dev", "bytes/dev",
-                  "coll-wire/dev", "T_comp(ms)", "T_mem(ms)", "T_coll(ms)",
-                  "bound", "useful", "roofline", "peak-mem/dev"]
 
 
 @dataclasses.dataclass(frozen=True)

@@ -2,7 +2,7 @@
 
 The KV sequence is walked in blocks by the minor (sequential) grid dimension
 with running max/sum/acc scratch — the same online softmax as prefill but with
-a 1-row query. This kernel is what makes ``decode_32k``/``long_500k`` cells
+a 1-row query. This kernel is what keeps long-context decode
 latency-sane: per-step HBM traffic is exactly one pass over the KV cache, and
 when the cache is sequence-sharded across chips the per-chip partials combine
 with one tiny LSE all-reduce (see models/common.sharded_decode_attention).

@@ -1,5 +1,3 @@
-from repro.launch.mesh import (data_shards, make_mesh, make_mesh_for,
-                               make_production_mesh, total_chips)
+from repro.launch.mesh import make_mesh, make_mesh_for
 
-__all__ = ["data_shards", "make_mesh", "make_mesh_for", "make_production_mesh",
-           "total_chips"]
+__all__ = ["make_mesh", "make_mesh_for"]

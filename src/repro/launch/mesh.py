@@ -1,6 +1,6 @@
-"""Production mesh definition (assignment-mandated shapes).
+"""Device meshes for the launchers, tests and examples.
 
-A FUNCTION, not a module-level constant: importing this module never touches
+Functions, not module-level constants: importing this module never touches
 jax device state.
 """
 from __future__ import annotations
@@ -20,12 +20,6 @@ def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
     return jax.make_mesh(shape, axes)
 
 
-def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
-
-
 def make_mesh_for(devices: int, model_parallel: int = 0) -> Mesh:
     """Small meshes for tests/examples: (data, model) over available devices."""
     model = model_parallel or (2 if devices % 2 == 0 and devices > 1 else 1)
@@ -39,16 +33,3 @@ def make_mesh_for(devices: int, model_parallel: int = 0) -> Mesh:
     data = devices // model
     return make_mesh((data, model), ("data", "model"))
 
-
-def data_shards(mesh: Mesh) -> int:
-    n = 1
-    for ax in ("pod", "data"):
-        n *= mesh.shape.get(ax, 1)
-    return n
-
-
-def total_chips(mesh: Mesh) -> int:
-    n = 1
-    for v in mesh.shape.values():
-        n *= v
-    return n

@@ -1,8 +1,7 @@
 """Training launcher: ``python -m repro.launch.train --arch <id> [...]``.
 
-On this CPU container it trains the arch's reduced smoke config end-to-end
-(full configs are exercised by the dry-run); on a real TPU slice the same
-entry point runs the full config on the production mesh (--full).
+On a CPU host it trains the arch's reduced smoke config end-to-end; on a
+TPU slice the same entry point runs the full config (--full).
 """
 from __future__ import annotations
 

@@ -9,7 +9,7 @@ from jax import lax
 
 from repro.models import common
 from repro.models.config import ModelConfig, Runtime
-from repro.parallel.sharding import Param, annotate, gather_weight
+from repro.parallel.sharding import Param, annotate
 
 Params = dict[str, Any]
 
@@ -32,18 +32,15 @@ def init_attn(key, cfg: ModelConfig, *, cross: bool = False) -> Params:
     return p
 
 
-def _w(p: Params, name: str, cd, rt: Runtime | None = None):
-    val = p[name].value.astype(cd)
-    if rt is not None and rt.fsdp_gather_weights:
-        val = gather_weight(val, p[name].axes)
-    return val
+def _w(p: Params, name: str, cd):
+    return p[name].value.astype(cd)
 
 
-def _project_qkv(p: Params, x, cfg: ModelConfig, rt: Runtime | None = None):
+def _project_qkv(p: Params, x, cfg: ModelConfig):
     cd = cfg.cdtype
-    q = jnp.einsum("bsd,dhk->bshk", x, _w(p, "wq", cd, rt))
-    k = jnp.einsum("bsd,dhk->bshk", x, _w(p, "wk", cd, rt))
-    v = jnp.einsum("bsd,dhk->bshk", x, _w(p, "wv", cd, rt))
+    q = jnp.einsum("bsd,dhk->bshk", x, _w(p, "wq", cd))
+    k = jnp.einsum("bsd,dhk->bshk", x, _w(p, "wk", cd))
+    v = jnp.einsum("bsd,dhk->bshk", x, _w(p, "wv", cd))
     return q, k, v
 
 
@@ -82,16 +79,15 @@ def attn_train(p: Params, x, cfg: ModelConfig, rt: Runtime, positions,
         h = (common.rmsnorm(x, p["norm"].value, cfg.norm_eps)
              if cfg.norm == "rmsnorm" else x)
         src = h if kv is None else kv
-        q = jnp.einsum("bsd,dhk->bshk", h, _w(p, "wq", cfg.cdtype, rt))
-        k = jnp.einsum("bsd,dhk->bshk", src, _w(p, "wk", cfg.cdtype, rt))
-        v = jnp.einsum("bsd,dhk->bshk", src, _w(p, "wv", cfg.cdtype, rt))
+        q = jnp.einsum("bsd,dhk->bshk", h, _w(p, "wq", cfg.cdtype))
+        k = jnp.einsum("bsd,dhk->bshk", src, _w(p, "wk", cfg.cdtype))
+        v = jnp.einsum("bsd,dhk->bshk", src, _w(p, "wv", cfg.cdtype))
         if kv is None:
             q, k = _rope(cfg, q, k, positions)
         q, k, v = _annotate_qkv(cfg, q, k, v)
         out = common.attention(q, k, v, causal=causal and kv is None,
-                               impl=rt.attn_impl, block_k=rt.block_k,
-                               p_dtype=jnp.dtype(rt.attn_p_dtype))
-        y = jnp.einsum("bshk,hkd->bsd", out, _w(p, "wo", cfg.cdtype, rt))
+                               impl=rt.attn_impl, block_k=rt.block_k)
+        y = jnp.einsum("bshk,hkd->bsd", out, _w(p, "wo", cfg.cdtype))
         return x + annotate(y, "batch", "seq", None), (k, v)
 
 
@@ -109,7 +105,7 @@ def _annotate_cache(c, cfg: ModelConfig, rt: Runtime, lead: tuple = ()):
     if rt.cache_shard == "head_dim":
         # split-K layout: the in-place cache write stays shard-local (a
         # DUS into a seq-sharded buffer makes GSPMD all-gather the whole
-        # cache — measured 16 GiB/step on jamba long_500k; §Perf).
+        # cache every step).
         return annotate(c, *lead, "batch", None, None, "kv_hd")
     if cfg.attn_parallelism == "heads":
         return annotate(c, *lead, "batch", "kv_seq", "kv_heads", None)
@@ -220,14 +216,14 @@ def init_mlp(key, cfg: ModelConfig, d_ff: int | None = None) -> Params:
     }
 
 
-def mlp_apply(p: Params, x, cfg: ModelConfig, rt: Runtime | None = None):
+def mlp_apply(p: Params, x, cfg: ModelConfig):
     with jax.named_scope("mlp"):
         h = common.rmsnorm(x, p["norm"].value, cfg.norm_eps)
         cd = cfg.cdtype
-        g = jnp.einsum("bsd,df->bsf", h, _w(p, "wg", cd, rt))
-        u = jnp.einsum("bsd,df->bsf", h, _w(p, "wu", cd, rt))
+        g = jnp.einsum("bsd,df->bsf", h, _w(p, "wg", cd))
+        u = jnp.einsum("bsd,df->bsf", h, _w(p, "wu", cd))
         g = annotate(jax.nn.silu(g) * u, "batch", "seq", "act_mlp")
-        y = jnp.einsum("bsf,fd->bsd", g, _w(p, "wd", cd, rt))
+        y = jnp.einsum("bsf,fd->bsd", g, _w(p, "wd", cd))
         return x + annotate(y, "batch", "seq", None)
 
 
@@ -305,8 +301,6 @@ def moe_apply(p: Params, x, cfg: ModelConfig, rt: Runtime):
             top_e = top_e[None]
             gates = gates[None]
         else:
-            if rt.moe_gather_decode and n_tok <= 256:
-                return _moe_gather_few_tokens(p, x, h, cfg)
             y, gates, top_e = _experts_capacity(p, h, cfg, rt)
         if "shared" in p:
             # shared expert runs densely on all tokens; reuse mlp without
@@ -404,40 +398,11 @@ def _experts_capacity(p: Params, h, cfg: ModelConfig, rt: Runtime):
         hu = jnp.einsum("gecd,edf->gecf", ein, p["wu"].value.astype(cd))
         hh = annotate(jax.nn.silu(hg) * hu, "batch", "experts", None, None)
         eout = jnp.einsum("gecf,efd->gecd", hh, p["wd"].value.astype(cd))
-        if rt.moe_combine_reshard:
-            # Reshard expert outputs back to token-major BEFORE the combine
-            # gather: GSPMD then moves each token's row once (all-to-all
-            # shaped) instead of all-gathering the whole [G,E,C,D] buffer to
-            # every model shard — §Perf knob for the EP return path.
-            eout = annotate(eout, "batch", None, None, None)
         flat = jnp.concatenate(
             [eout.reshape(g, e * cap, d), jnp.zeros((g, 1, d), cd)], axis=1)
         gathered = jnp.take_along_axis(flat, slot[..., None], axis=1)   # [G,N,D]
         out = out + gathered * top_w[..., slot_k, None].astype(cd)
     return out.reshape(b, s, d), gates, top_e
-
-
-def _moe_gather_few_tokens(p: Params, x, h, cfg: ModelConfig):
-    """Decode-path MoE: gather ONLY the routed experts' weights.
-
-    Dense capacity dispatch reads every expert's FFN from HBM even for one
-    token; at batch<=256 tokens it is strictly cheaper to move k expert
-    weight slices per token than all E of them — this is what drops the
-    long_500k/decode collective+memory terms (§Perf)."""
-    b, s, d = x.shape
-    k, cd = cfg.top_k, cfg.cdtype
-    hf = h.reshape(b * s, d)
-    _, top_w, top_e = _route(p, hf, cfg)                          # [N,k]
-    wg = p["wg"].value[top_e]        # [N,k,D,F] gathered slices
-    wu = p["wu"].value[top_e]
-    wd = p["wd"].value[top_e]
-    hg = jnp.einsum("nd,nkdf->nkf", hf, wg.astype(cd))
-    hu = jnp.einsum("nd,nkdf->nkf", hf, wu.astype(cd))
-    eo = jnp.einsum("nkf,nkfd->nkd", jax.nn.silu(hg) * hu, wd.astype(cd))
-    y = jnp.einsum("nk,nkd->nd", top_w.astype(cd), eo).reshape(b, s, d)
-    if "shared" in p:
-        y = y + (mlp_apply(p["shared"], x, cfg) - x)
-    return x + y, jnp.zeros((), jnp.float32)
 
 
 def _load_balance_loss(gates, top_e, n_experts: int) -> jax.Array:

@@ -2,8 +2,8 @@
 
 Attention comes in three implementations with one math:
   * ``plain``      — einsum + mask; short sequences / smoke tests.
-  * ``blockwise``  — lax.scan online-softmax over KV blocks; memory-bounded,
-                     used by full-size dry-runs (XLA-native flash equivalent),
+  * ``blockwise``  — lax.scan online-softmax over KV blocks; memory-bounded
+                     (XLA-native flash equivalent), used by long prefills,
                      and serves as the reference for the Pallas kernel.
   * ``pallas``     — kernels/flash_attention (real TPU path, opt-in).
 GQA is native (kv heads broadcast over groups); CP (context parallelism) is
@@ -126,11 +126,11 @@ def plain_attention(q, k, v, *, causal: bool, q_offset: jax.Array | int = 0,
 
 
 def blockwise_attention(q, k, v, *, causal: bool, block_k: int = 1024,
-                        q_offset: int = 0, p_dtype=jnp.float32) -> jax.Array:
+                        q_offset: int = 0) -> jax.Array:
     """Online-softmax over KV blocks via lax.scan: O(S·bk) live memory.
 
-    This is what makes 32k-prefill dry-runs fit: scores are never
-    materialized beyond [*, Sq, block_k].
+    This is what makes long prefills fit: scores are never materialized
+    beyond [*, Sq, block_k].
     """
     b, sq, h, d = q.shape
     _, sk, kh, _ = k.shape
@@ -153,12 +153,7 @@ def blockwise_attention(q, k, v, *, causal: bool, block_k: int = 1024,
         p = jnp.exp(logits - m_new)
         alpha = jnp.exp(m - m_new)
         l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        # p_dtype=bf16 halves the dominant HBM term of XLA blockwise
-        # attention (the [.., sq, bk] prob tile written between the two dots)
-        # at <=1e-3 softmax error — §Perf knob; f32 is the faithful default.
-        acc = acc * alpha[..., 0, None] + jnp.einsum(
-            "bkgqs,bskd->bkgqd", p.astype(p_dtype), vblk.astype(p_dtype)
-        ).astype(jnp.float32)
+        acc = acc * alpha[..., 0, None] + jnp.einsum("bkgqs,bskd->bkgqd", p, vblk)
         return (m_new, l, acc), None
 
     m0 = jnp.full((b, kh, g, sq, 1), NEG_INF, jnp.float32)
@@ -216,8 +211,7 @@ def decode_attention_stacked(q, k, v, period, kv_len) -> jax.Array:
 
 
 def attention(q, k, v, *, causal: bool = True, impl: str = "auto",
-              q_offset: int = 0, block_k: int = 1024,
-              p_dtype=jnp.float32) -> jax.Array:
+              q_offset: int = 0, block_k: int = 1024) -> jax.Array:
     sk = k.shape[1]
     if impl == "auto":
         impl = "blockwise" if sk >= 4096 else "plain"
@@ -225,7 +219,7 @@ def attention(q, k, v, *, causal: bool = True, impl: str = "auto",
         return plain_attention(q, k, v, causal=causal, q_offset=q_offset)
     if impl == "blockwise":
         return blockwise_attention(q, k, v, causal=causal, q_offset=q_offset,
-                                   block_k=min(block_k, sk), p_dtype=p_dtype)
+                                   block_k=min(block_k, sk))
     if impl == "pallas":
         from repro.kernels.ops import flash_attention
         return flash_attention(q, k, v, causal=causal)

@@ -174,14 +174,4 @@ class Runtime:
     xent_chunk: int = 512
     scan_layers: bool = True
     use_pallas: bool = False
-    max_cache_len: int = 0           # decode cells set this
-    # ---- perf knobs (§Perf hillclimb; defaults = paper-faithful baseline)
-    attn_p_dtype: str = "float32"    # softmax-prob dtype for the PV matmul
     cache_shard: str = "seq"         # decode KV cache: "seq" | "head_dim"
-    moe_combine_reshard: bool = False  # reshard expert outputs before gather
-    moe_gather_decode: bool = False  # few-token MoE: gather top-k expert
-                                     # weights instead of dense-all-experts
-    infer_sharding: bool = False     # decode cells: drop FSDP (params stay
-                                     # model-sharded, replicated over data)
-    fsdp_gather_weights: bool = False  # ZeRO-3 JIT weight gather (vs GSPMD
-                                       # activation partial-sum all-reduce)

@@ -1,6 +1,6 @@
 """Encoder-decoder backbone (seamless-m4t text/speech transformer).
 
-The speech frontend is a STUB per the assignment: ``input_specs`` feeds
+The speech frontend is a stub: the caller feeds
 precomputed frame embeddings [B, Se, D] to the encoder. The decoder is a
 standard causal transformer with per-layer cross-attention to the encoder
 memory; decode caches = self-attn KV + precomputed cross KV.

@@ -64,7 +64,7 @@ def block_train(p: Params, x, layer: Layer, cfg: ModelConfig, rt: Runtime,
         x, cache = xlstm.slstm_train(p["mixer"], x, cfg, rt)
     aux = jnp.zeros((), jnp.float32)
     if ffn == "dense":
-        x = blocks.mlp_apply(p["ffn"], x, cfg, rt)
+        x = blocks.mlp_apply(p["ffn"], x, cfg)
     elif ffn == "moe":
         x, aux = blocks.moe_apply(p["ffn"], x, cfg, rt)
     return x, aux, cache
@@ -93,7 +93,7 @@ def block_decode(p: Params, x, cache: Params, period, pos, layer: Layer,
             lambda a, n: lax.dynamic_update_index_in_dim(
                 a, n.astype(a.dtype), period, 0), cache, new)
     if ffn == "dense":
-        x = blocks.mlp_apply(p["ffn"], x, cfg, rt)
+        x = blocks.mlp_apply(p["ffn"], x, cfg)
     elif ffn == "moe":
         x, _ = blocks.moe_apply(p["ffn"], x, cfg, rt)
     return x, cache

@@ -1,6 +1,6 @@
 """GPipe-style pipeline parallelism via shard_map + ppermute (opt-in PP).
 
-Stages live on the ``stage`` mesh axis (on the production mesh this is the
+Stages live on the ``stage`` mesh axis (on a multi-pod mesh this is the
 ``pod`` axis: one pipeline stage per pod, DP x TP inside the pod). The
 schedule is the classic GPipe fill-drain loop: T = M + S - 1 ticks, activations
 hop stage->stage+1 by collective-permute each tick, microbatch i occupies

@@ -1,4 +1,4 @@
-"""Logical-axis sharding: DP / FSDP / TP / EP / CP / SP on the production mesh.
+"""Logical-axis sharding: DP / FSDP / TP / EP / CP / SP on a device mesh.
 
 Models annotate parameters (via :class:`Param` boxes) and activations (via
 :func:`annotate`) with *logical* axis names; a :class:`ShardingRules` table
@@ -119,7 +119,7 @@ def lm_rules(*, fsdp: bool = True, context_parallel_seq: bool = False,
         "act_vocab": "model",
         "cp_seq": "model" if context_parallel_seq else None,
         "kv_seq": "model",    # decode caches: flash-decode partial softmax
-        "kv_hd": "model",     # decode caches: split-K alternative (§Perf)
+        "kv_hd": "model",     # decode caches: split-K alternative
         # params
         "embed": fsdp_axes if fsdp else None,
         "heads": "model",
@@ -185,31 +185,83 @@ def annotate(x: jax.Array, *axes: str | None) -> jax.Array:
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
-def gather_weight(value: jax.Array, axes: Sequence[str | None]) -> jax.Array:
-    """ZeRO-3 just-in-time gather: constrain a weight to its sharding WITHOUT
-    the data axes, forcing GSPMD to all-gather the FSDP shards right before
-    use (wire = weight bytes once) instead of all-reducing activation
-    partial-sums (wire = activation bytes per matmul) — §Perf knob."""
-    ctx = _CTX.get()
-    if ctx is None:
-        return value
-    mesh, rules = ctx
-    spec = rules.resolve(axes[-value.ndim:], value.shape, mesh)
-    stripped = []
-    for entry in spec:
-        parts = (entry,) if isinstance(entry, str) else (entry or ())
-        parts = tuple(p for p in parts if p not in ("data", "pod"))
-        stripped.append(parts[0] if len(parts) == 1 else (parts or None))
-    return jax.lax.with_sharding_constraint(
-        value, NamedSharding(mesh, P(*stripped)))
-
-
 def param_shardings(boxed: Any, mesh: Mesh, rules: ShardingRules) -> Any:
     """NamedSharding pytree for a boxed param tree (for jit in_shardings)."""
     def one(p: Param):
         shape = getattr(p.value, "shape", None)
         return NamedSharding(mesh, rules.resolve(p.axes, shape, mesh))
     return jax.tree_util.tree_map(one, boxed, is_leaf=is_param)
+
+
+def opt_shardings(params_boxed: Any, state_shapes: Any, mesh: Mesh,
+                  rules: ShardingRules) -> dict:
+    """Optimizer-state shardings mirror the owning param's sharding."""
+    def for_param(p: Param, st):
+        spec = rules.resolve(p.axes, p.value.shape, mesh)
+        if isinstance(st, dict) and set(st) == {"q", "scale"}:
+            # int8 moments: q has the param's shape (inherits its sharding);
+            # scale is [..., nblocks] — keep the last-axis sharding only if
+            # the block count still divides.
+            entries = list(spec) + [None] * (len(p.value.shape) - len(list(spec)))
+            s_entries = list(entries)
+            last = s_entries[-1] if s_entries else None
+            nb = st["scale"].shape[-1]
+            if last is not None:
+                size = 1
+                for a in ((last,) if isinstance(last, str) else last):
+                    size *= mesh.shape.get(a, 1)
+                if nb % size != 0:
+                    s_entries[-1] = None
+            return {"q": NamedSharding(mesh, P(*entries)),
+                    "scale": NamedSharding(mesh, P(*s_entries))}
+        return NamedSharding(mesh, spec)
+
+    m_sh = jax.tree_util.tree_map(for_param, params_boxed, state_shapes["m"],
+                                  is_leaf=is_param)
+    v_sh = jax.tree_util.tree_map(for_param, params_boxed, state_shapes["v"],
+                                  is_leaf=is_param)
+    return {"m": m_sh, "v": v_sh,
+            "count": NamedSharding(mesh, P())}
+
+
+def cache_shardings(cache_shapes: Any, mesh: Mesh, batch_axes,
+                    mode: str = "seq") -> Any:
+    """KV-cache shardings by leaf name: batch over data axes, SSM inner dims
+    over 'model', and the KV cache either ``seq``-sharded over 'model'
+    (flash-decode partial softmax) or ``head_dim``-sharded (split-K attention:
+    the decode step's in-place cache write stays shard-local); ``mode`` is
+    ``Runtime.cache_shard``."""
+    def one(path, sds):
+        name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
+        nd = len(sds.shape)
+        def div(i, ax):
+            if ax is None:
+                return False
+            size = 1
+            for a in ((ax,) if isinstance(ax, str) else ax):
+                size *= mesh.shape.get(a, 1)
+            return sds.shape[i] % size == 0 and size > 1
+        spec: list = [None] * nd
+        if nd >= 2 and div(1, batch_axes):
+            spec[1] = batch_axes        # [layers, batch, ...]
+        if name in ("k", "v", "ck", "cv") and nd == 5:
+            if mode == "head_dim" and div(4, "model"):
+                spec[4] = "model"       # split-K: local DUS, psum'd logits
+            elif div(2, "model"):
+                spec[2] = "model"       # cache seq (flash-decode combine)
+        elif name == "h" and nd == 4 and div(2, "model"):
+            spec[2] = "model"           # mamba inner
+        elif name == "conv" and nd == 4 and div(3, "model"):
+            spec[3] = "model"
+        elif name == "c" and nd == 5 and div(4, "model"):
+            spec[4] = "model"           # mlstm value dim
+        elif name in ("n",) and nd == 4 and div(3, "model"):
+            spec[3] = "model"
+        elif name in ("c", "n", "h", "m") and nd == 3 and div(2, "model"):
+            spec[2] = "model"           # slstm [layers,B,D]
+        return NamedSharding(mesh, P(*spec))
+
+    return jax.tree_util.tree_map_with_path(one, cache_shapes)
 
 
 def spec_tree(boxed: Any, mesh: Mesh, rules: ShardingRules) -> Any:

@@ -51,22 +51,6 @@ def tree_params(tree: Any) -> int:
     return sum(int(np.prod(l.shape)) for l in jax.tree_util.tree_leaves(tree))
 
 
-def human_bytes(n: float) -> str:
-    for unit in ("B", "KiB", "MiB", "GiB", "TiB", "PiB"):
-        if abs(n) < 1024.0:
-            return f"{n:.2f}{unit}"
-        n /= 1024.0
-    return f"{n:.2f}EiB"
-
-
-def human_flops(n: float) -> str:
-    for unit in ("", "K", "M", "G", "T", "P", "E"):
-        if abs(n) < 1000.0:
-            return f"{n:.2f}{unit}FLOP"
-        n /= 1000.0
-    return f"{n:.2f}ZFLOP"
-
-
 class _JsonEncoder(json.JSONEncoder):
     def default(self, o: Any) -> Any:  # noqa: D102
         if dataclasses.is_dataclass(o) and not isinstance(o, type):

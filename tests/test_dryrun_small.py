@@ -1,31 +1,9 @@
-"""Dry-run machinery on a small 8-device mesh (subprocess), plus pure-python
-pieces of launch/cells."""
+"""The sharded path on a small 8-device host mesh (subprocess): a train step
+and the decode step lower and compile, and the cache shardings of
+``parallel.sharding``."""
 import pytest
 
-from repro.configs.registry import SHAPES, all_arch_ids, get
-from repro.launch import cells
 from tests._subproc import run_with_devices
-
-
-def test_input_specs_all_cells_defined():
-    for arch in all_arch_ids():
-        spec = get(arch)
-        for shape in SHAPES:
-            if shape in spec.skips:
-                continue
-            specs = cells.input_specs(arch, shape)
-            assert specs, (arch, shape)
-            for k, v in specs.items():
-                assert all(d > 0 for d in v.shape), (arch, shape, k)
-
-
-def test_long500k_skips_are_full_attention_only():
-    for arch in all_arch_ids():
-        spec = get(arch)
-        if arch in ("jamba-v0.1-52b", "xlstm-350m"):
-            assert "long_500k" not in spec.skips
-        else:
-            assert "long_500k" in spec.skips
 
 
 @pytest.mark.slow
@@ -62,8 +40,7 @@ with shd.use_sharding(mesh, rules):
         np_, ns = optim.apply_update(p, g, s, ocfg)
         return np_, ns, l
 
-    from repro.launch.cells import opt_shardings
-    osh = opt_shardings(params, ost, mesh, rules)
+    osh = shd.opt_shardings(params, ost, mesh, rules)
     compiled = jax.jit(step, in_shardings=(psh, osh, bsh)).lower(
         params, ost, batch).compile()
     ma = compiled.memory_analysis()
@@ -78,7 +55,7 @@ print("COMPILED")
 def test_cache_shardings_divisibility():
     import jax
     import jax.numpy as jnp
-    from repro.launch.cells import cache_shardings
+    from repro.parallel.sharding import cache_shardings
     mesh = jax.make_mesh((1, 1), ("data", "model"),
                          axis_types=(jax.sharding.AxisType.Auto,) * 2)
     shapes = {"l0": {"k": jax.ShapeDtypeStruct((2, 1, 7, 3, 8), jnp.bfloat16)}}
@@ -91,14 +68,13 @@ def test_cache_shardings_divisibility():
 @pytest.mark.parametrize("mode", ["seq", "head_dim"])
 def test_small_mesh_decode_step_lowers(mode):
     """The decode step, with the stacked cache carried through the layer scan
-    and sharded as ``launch/cells.py`` shards it, compiles on a (2 pod,
+    and sharded by ``sharding.cache_shardings``, compiles on a (2 pod,
     2 data, 2 model) mesh for a dense and a hybrid period, donating the
     cache in place."""
     out = run_with_devices(f"""
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.registry import get
-from repro.launch.cells import cache_shardings
 from repro.launch.mesh import make_mesh
 from repro.models import transformer
 from repro.models.config import Runtime
@@ -115,7 +91,7 @@ for arch in ("granite-3-8b", "jamba-v0.1-52b"):
                                 jax.random.PRNGKey(0))
         cache = jax.eval_shape(
             lambda: transformer.init_cache(cfg, 8, 256, cfg.cdtype))
-        c_sh = cache_shardings(cache, mesh, ("pod", "data"), mode={mode!r})
+        c_sh = shd.cache_shardings(cache, mesh, ("pod", "data"), mode={mode!r})
         tok_sh = NamedSharding(mesh, P(("pod", "data"), None))
         step = jax.jit(
             lambda p, c, t, q: transformer.decode_step(p, c, t, q, cfg, rt),

@@ -1,5 +1,9 @@
 """Model math: attention impl equivalence, rope/mrope, moe routing, ssm/xlstm
 recurrent vs chunked equivalence."""
+import dataclasses
+import pathlib
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -203,7 +207,7 @@ def _decode_by_period(params, cache, tokens, pos, cfg, rt):
             elif mixer == "slstm":
                 x, c = xlstm.slstm_decode(p["mixer"], x, c, cfg)
             if ffn == "dense":
-                x = blocks.mlp_apply(p["ffn"], x, cfg, rt)
+                x = blocks.mlp_apply(p["ffn"], x, cfg)
             new[f"l{i}"] = c
         caches.append(new)
     h = common.rmsnorm(x, params["final_norm"].value)
@@ -267,3 +271,18 @@ def test_decode_step_matches_per_period_loop(arch, per_row):
                         jax.tree_util.tree_leaves(want_c)):
             assert g.dtype == w.dtype
             np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+# ------------------------------------------------------------ runtime options
+@pytest.mark.parametrize("field", [f.name for f in dataclasses.fields(Runtime)])
+def test_runtime_field_is_read(field):
+    """Every ``Runtime`` option selects something: ``rt.<field>`` is read
+    somewhere in the package outside its definition."""
+    import repro
+
+    root = pathlib.Path(repro.__file__).parent
+    pattern = re.compile(rf"\brt\.{field}\b")
+    readers = [path for path in root.rglob("*.py")
+               if path != root / "models" / "config.py"
+               and pattern.search(path.read_text())]
+    assert readers, f"Runtime.{field} is read by no code"

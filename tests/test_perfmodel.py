@@ -1,4 +1,4 @@
-"""Roofline model + HLO latency estimator.
+"""Hardware peaks + HLO latency estimator.
 
 The estimator tests are *oracles*: hand-written HLO modules against a
 synthetic LatencyDB where the exact expected nanoseconds are computed by hand
@@ -18,13 +18,6 @@ from repro.core import chains, hlo_analysis, perfmodel
 from repro.core.latency_db import LatencyDB, LatencyRecord
 
 
-def _roof(flops, bts, hlo=""):
-    return perfmodel.Roofline().analyze(
-        arch="a", shape="s", mesh="m", chips=256,
-        cost={"flops": flops, "bytes accessed": bts}, hlo_text=hlo,
-        model_flops=flops * 256 * 0.5)
-
-
 def _rec(op, ns, cat="fp32", dtype="float32", opt="O3", notes="", env=None):
     env = env or {"device_kind": "cpu", "backend": "cpu", "jax_version": "x"}
     return LatencyRecord(op=op, category=cat, dtype=dtype, opt_level=opt,
@@ -33,30 +26,9 @@ def _rec(op, ns, cat="fp32", dtype="float32", opt="O3", notes="", env=None):
                          notes=notes, **env)
 
 
-def test_dominant_term():
-    r = _roof(197e12 * 0.01, 819e9 * 0.001)
-    assert r.dominant == "compute"
-    r = _roof(197e12 * 0.001, 819e9 * 0.01)
-    assert r.dominant == "memory"
-
-
-def test_terms_math():
-    r = _roof(flops=197e12, bts=819e9)
-    assert r.t_compute == pytest.approx(1.0)
-    assert r.t_memory == pytest.approx(1.0)
-    assert r.useful_ratio == pytest.approx(0.5)
-    assert r.roofline_fraction == pytest.approx(0.5)
-
-
 def test_knee():
     assert perfmodel.TPU_V5E.arithmetic_intensity_knee == pytest.approx(
         197e12 / 819e9)
-
-
-def test_markdown_row_shape():
-    r = _roof(1e12, 1e10)
-    row = perfmodel.Roofline.markdown_row(r)
-    assert len(row) == len(perfmodel.Roofline.MD_HEADERS)
 
 
 # =========================================================== estimator oracles
