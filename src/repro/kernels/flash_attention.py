@@ -37,9 +37,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     k_start = ki * block_k
 
     def compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale          # [bq, d]
-        k = k_ref[0, 0].astype(jnp.float32)                  # [bk, d]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # [bq, bk]
+        # q and k enter the MXU as stored; scores and the softmax state are
+        # float32, and the scale applies to the float32 scores
+        s = jax.lax.dot_general(q_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
         if causal:
             qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -50,8 +51,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         p = jnp.exp(s - m_new)                                # [bq, bk]
         alpha = jnp.exp(m_prev - m_new)                       # [bq, 1]
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        v = v_ref[0, 0].astype(jnp.float32)                   # [bk, d]
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(p, v)
+        # P enters the MXU in V's type, as a default-precision float32 dot
+        # rounds it on a TPU; the accumulator stays float32
+        v = v_ref[...]                                        # [bk, d]
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     if causal:
@@ -63,7 +67,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     @pl.when(ki == num_k - 1)
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def default_blocks(sq: int, sk: int, d: int) -> tuple[int, int]:
@@ -78,7 +82,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, scale: float | None = None,
                     block_q: int | None = None, block_k: int | None = None,
                     interpret: bool | None = None) -> jax.Array:
-    """q: [B,Sq,H,D]; k,v: [B,Sk,KH,D] with H % KH == 0. Returns [B,Sq,H,D]."""
+    """q: [B,Sq,H,D]; k,v: [B,Sk,KH,D] with H % KH == 0. Returns [B,Sq,H,D].
+
+    The dots take q, k and v in their own type with float32 accumulation,
+    so float32 inputs keep float32 dots. Causal blocks above the diagonal
+    are skipped.
+    """
     b, sq, h, d = q.shape
     _, sk, kh, _ = k.shape
     assert h % kh == 0, (h, kh)
@@ -94,10 +103,16 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     num_q, num_k = cdiv(sq, bq), cdiv(sk, bk)
 
     # [B,H,S,D] layout inside the kernel: head-major so each grid cell streams
-    # contiguous [block, d] tiles.
+    # contiguous [block, d] tiles. XLA writes q and k so from the fusions that
+    # make them, and a [B,S,H*D] view of the heads would cost it copies.
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
+    q_spec = pl.BlockSpec((None, None, bq, d),
+                          lambda bi, hi, qi, ki: (bi, hi, qi, 0))
+    kv_spec = pl.BlockSpec(
+        (None, None, bk, d),
+        lambda bi, hi, qi, ki: (bi, hi // g, ki, 0))
 
     kernel = functools.partial(
         _flash_kernel, scale=scale, causal=causal, sq=sq, sk=sk,
@@ -106,19 +121,15 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     out = pl.pallas_call(
         kernel,
         grid=(b, h, num_q, num_k),
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda bi, hi, qi, ki, g=g: (bi, hi // g, ki, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda bi, hi, qi, ki, g=g: (bi, hi // g, ki, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
+        name="flash_attention",
         interpret=interpret,
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3)
-

@@ -5,7 +5,11 @@ Attention comes in three implementations with one math:
   * ``blockwise``  — lax.scan online-softmax over KV blocks; memory-bounded
                      (XLA-native flash equivalent), used by long prefills,
                      and serves as the reference for the Pallas kernel.
-  * ``pallas``     — kernels/flash_attention (real TPU path, opt-in).
+  * ``pallas``     — kernels/flash_attention, the Pallas TPU kernel.
+``auto`` runs causal self-attention (one length, no offset) through the
+kernel on a TPU with no sharding mesh active (:func:`flash_prefill`), and
+everything else, and every call on another platform or under a mesh,
+through ``blockwise`` from 4,096 keys and ``plain`` below.
 GQA is native (kv heads broadcast over groups); CP (context parallelism) is
 purely a matter of the logical-axis annotations callers apply.
 """
@@ -20,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro import tracing
 from repro.parallel import sharding
 from repro.parallel.sharding import Param, annotate
 
@@ -210,16 +215,62 @@ def decode_attention_stacked(q, k, v, period, kv_len) -> jax.Array:
         default=sliced)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def flash_prefill(q, k, v, block_k: int = 1024,
+                  interpret: bool | None = None) -> jax.Array:
+    """Causal self-attention through the Pallas flash kernel at any length.
+    q: [B,S,H,D]; k,v: [B,S,KH,D].
+
+    q, k and v are padded on S to a multiple of 128, and the padded query
+    rows dropped: every padded key lies after every real query, so the
+    causal mask hides it. ``pallas_call`` has no reverse rule, so the
+    backward pass is :func:`blockwise_attention`'s (``block_k`` keys a
+    block) on the saved q, k, v.
+    """
+    from repro.kernels.flash_attention import flash_attention
+
+    s = q.shape[1]
+    pad = ((0, 0), (0, -s % 128), (0, 0), (0, 0))
+    out = flash_attention(*(jnp.pad(x, pad) for x in (q, k, v)), causal=True,
+                          interpret=interpret)
+    return out[:, :s]
+
+
+def _flash_prefill_fwd(q, k, v, block_k, interpret):
+    return flash_prefill(q, k, v, block_k, interpret), (q, k, v)
+
+
+def _flash_prefill_bwd(block_k, interpret, res, g):
+    _, vjp = jax.vjp(functools.partial(blockwise_attention, causal=True,
+                                       block_k=block_k), *res)
+    return vjp(g)
+
+
+flash_prefill.defvjp(_flash_prefill_fwd, _flash_prefill_bwd)
+
+
 def attention(q, k, v, *, causal: bool = True, impl: str = "auto",
               q_offset: int = 0, block_k: int = 1024) -> jax.Array:
     sk = k.shape[1]
+    block_k = min(block_k, sk)
     if impl == "auto":
         impl = "blockwise" if sk >= 4096 else "plain"
+        if (causal and q.shape[1] == sk and q_offset == 0
+                and sharding.current() is None):
+            # the choice rests on causality, shapes and the mesh; the
+            # platform makes it when the program is lowered
+            tracing.count("attn.flash_prefill")
+            return lax.platform_dependent(
+                q, k, v,
+                tpu=functools.partial(flash_prefill, block_k=block_k,
+                                      interpret=False),
+                default=functools.partial(attention, causal=True, impl=impl,
+                                          block_k=block_k))
     if impl == "plain":
         return plain_attention(q, k, v, causal=causal, q_offset=q_offset)
     if impl == "blockwise":
         return blockwise_attention(q, k, v, causal=causal, q_offset=q_offset,
-                                   block_k=min(block_k, sk))
+                                   block_k=block_k)
     if impl == "pallas":
         from repro.kernels.ops import flash_attention
         return flash_attention(q, k, v, causal=causal)

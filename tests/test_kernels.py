@@ -42,6 +42,86 @@ def test_flash_attention_noncausal():
     np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
 
 
+def _bf16_qkv(s, h, kh, d, seed=0):
+    ks = jax.random.split(jax.random.fold_in(KEY, seed), 3)
+    return tuple(jax.random.normal(key, (1, s, n, d), jnp.float32).astype(jnp.bfloat16)
+                 for key, n in zip(ks, (h, kh, kh)))
+
+
+@pytest.mark.parametrize("s", [200, 128, 77])
+def test_flash_prefill_pads_any_length(s):
+    """Prefill's flash path at prompt lengths that are not, and are, a
+    multiple of 128 (bfloat16, 8 query heads over 2 KV heads of 128): the
+    padded keys lie after every real query, so the causal mask hides them."""
+    from repro.models import common
+
+    q, k, v = _bf16_qkv(s, 8, 2, 128)
+    out = common.flash_prefill(q, k, v, interpret=True)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    want = ref.ref_attention(q, k, v, causal=True)
+    np.testing.assert_allclose(out.astype(jnp.float32), want.astype(jnp.float32),
+                               **tol(jnp.bfloat16))
+
+
+def _plain_at_tpu_default_precision(q, k, v):
+    """``common.plain_attention`` as a TPU runs it: its float32 dots at
+    default precision take bfloat16 operands (the scaled q and P), where
+    a CPU's float32 dots are exact."""
+    b, s, h, d = q.shape
+    kh = k.shape[2]
+
+    def rounded(x):
+        return x.astype(jnp.bfloat16).astype(jnp.float32)
+
+    qf = rounded(q.astype(jnp.float32).reshape(b, s, kh, h // kh, d) * d ** -0.5)
+    logits = jnp.einsum("bqkgd,bskd->bkgqs", qf, k.astype(jnp.float32))
+    causal = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
+    p = jax.nn.softmax(jnp.where(causal, logits, -1e30), axis=-1)
+    out = jnp.einsum("bkgqs,bskd->bqkgd", rounded(p), v.astype(jnp.float32))
+    return out.reshape(b, s, h, d).astype(q.dtype)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_flash_prefill_precision_no_worse_than_plain(seed):
+    """The kernel takes bfloat16 q, k and P into its dots: against a float32
+    reference over the same bfloat16 inputs its error, largest and RMS, is
+    no worse than that of the XLA path it replaces on a TPU."""
+    from repro.models import common
+
+    q, k, v = _bf16_qkv(200, 8, 2, 128, seed)
+    want = ref.ref_attention(*(x.astype(jnp.float32) for x in (q, k, v)))
+
+    def errors(out):
+        e = out.astype(jnp.float32) - want
+        return float(jnp.max(jnp.abs(e))), float(jnp.sqrt(jnp.mean(e * e)))
+
+    kernel = errors(common.flash_prefill(q, k, v, interpret=True))
+    plain = errors(_plain_at_tpu_default_precision(q, k, v))
+    assert kernel[0] <= plain[0] and kernel[1] <= plain[1], (kernel, plain)
+
+
+def test_flash_prefill_grad_is_blockwise_grad():
+    """``pallas_call`` has no reverse rule: the wrapper's backward pass is
+    blockwise attention's, so training through it gets that gradient."""
+    from repro.models import common
+
+    ks = jax.random.split(KEY, 4)
+    q = jax.random.normal(ks[0], (2, 96, 4, 32))
+    k = jax.random.normal(ks[1], (2, 96, 2, 32))
+    v = jax.random.normal(ks[2], (2, 96, 2, 32))
+    w = jax.random.normal(ks[3], q.shape)
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(attn(q, k, v) * w)
+
+    got = jax.grad(loss(lambda q, k, v: common.flash_prefill(
+        q, k, v, 32, True)), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: common.blockwise_attention(
+        q, k, v, causal=True, block_k=32)), argnums=(0, 1, 2))(q, k, v)
+    for g, wg in zip(got, want):
+        np.testing.assert_allclose(g, wg, atol=2e-5, rtol=0)
+
+
 @pytest.mark.parametrize("b,s,h,kh,d", [(2, 256, 8, 2, 64), (3, 128, 4, 4, 32),
                                         (1, 512, 2, 1, 128)])
 def test_flash_decode(b, s, h, kh, d):

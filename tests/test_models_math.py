@@ -29,6 +29,89 @@ def test_blockwise_matches_plain(sq, sk, block):
     np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5)
 
 
+def _program(fn, *args) -> str:
+    """``fn``'s program compiled here, without its module's name, metadata
+    and source tables, and with its names numbered in order of appearance."""
+    text = jax.jit(fn).lower(*args).compile().as_text().split("\nFileNames")[0]
+    text = re.sub(r"^HloModule \S+", "HloModule", text)
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    names: dict = {}
+    return re.sub(r"%[\w.\-]+",
+                  lambda m: names.setdefault(m.group(), f"%{len(names)}"), text)
+
+
+def _qkv(s, h=4, kh=2, d=32):
+    ks = jax.random.split(KEY, 3)
+    return tuple(jax.random.normal(key, (1, s, n, d))
+                 for key, n in zip(ks, (h, kh, kh)))
+
+
+@pytest.mark.parametrize("s,xla", [(64, "plain"), (4096, "blockwise")])
+def test_auto_attention_off_tpu_is_the_xla_path(s, xla):
+    """Off a TPU, ``auto`` causal self-attention computes and compiles to
+    exactly what the XLA path it chose by length does, the kernel's branch
+    being dropped when the program is lowered for this platform."""
+    q, k, v = _qkv(s, kh=1, d=8) if s >= 4096 else _qkv(s)
+
+    def auto(q, k, v):
+        return common.attention(q, k, v)
+
+    def explicit(q, k, v):
+        return common.attention(q, k, v, impl=xla)
+
+    np.testing.assert_array_equal(auto(q, k, v), explicit(q, k, v))
+    assert _program(auto, q, k, v) == _program(explicit, q, k, v)
+
+
+def test_auto_attention_under_a_mesh_is_plain():
+    """Under an active sharding mesh ``auto`` never stages the kernel, so
+    it lowers as ``plain``."""
+    from jax.sharding import Mesh
+
+    from repro.parallel import sharding
+
+    q, k, v = _qkv(64)
+    mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
+    with sharding.use_sharding(mesh, sharding.lm_rules()):
+        auto = _program(lambda q, k, v: common.attention(q, k, v), q, k, v)
+        plain = _program(lambda q, k, v: common.attention(q, k, v, impl="plain"),
+                         q, k, v)
+    assert auto == plain
+
+
+def test_flash_prefill_counter_counts_kernel_selections():
+    """``attn.flash_prefill`` counts each trace in which ``auto`` stages the
+    kernel: causal self-attention with no offset and no mesh, only."""
+    from jax.sharding import Mesh
+
+    from repro import tracing
+    from repro.parallel import sharding
+
+    q, k, v = _qkv(64)
+    mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
+
+    def selections(run) -> int:
+        tracing.collect()
+        run()
+        return sum(c.n for c in tracing.collect()[1]
+                   if c.name == "attn.flash_prefill")
+
+    tracing.enable()
+    try:
+        assert selections(lambda: common.attention(q, k, v)) == 1
+        assert selections(lambda: common.attention(q, k, v, causal=False)) == 0
+        assert selections(lambda: common.attention(q[:, :32], k, v)) == 0
+        assert selections(lambda: common.attention(q, k, v, q_offset=8)) == 0
+        for impl in ("plain", "blockwise", "pallas"):
+            assert selections(lambda: common.attention(q, k, v, impl=impl)) == 0
+        with sharding.use_sharding(mesh, sharding.lm_rules()):
+            assert selections(lambda: common.attention(q, k, v)) == 0
+        jitted = jax.jit(lambda q, k, v: common.attention(q, k, v))
+        assert selections(lambda: [jitted(q, k, v) for _ in range(3)]) == 1
+    finally:
+        tracing.disable()
+
+
 def test_decode_attention_matches_plain_lastrow():
     ks = jax.random.split(KEY, 3)
     s = 64
